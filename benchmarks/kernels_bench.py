@@ -485,6 +485,8 @@ def main(argv=None) -> None:
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced shapes; assert every kernel runs")

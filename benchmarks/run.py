@@ -1,7 +1,8 @@
 """Benchmark harness master: one entry per paper table/figure + roofline.
 
 Prints ``name,us_per_call,derived`` CSV per the repo convention and writes
-the full structured results to artifacts/bench_results.json.
+the full structured results to artifacts/bench_results.json.  Exits
+non-zero when any entry raised (every entry still runs and is reported).
 
     PYTHONPATH=src python -m benchmarks.run [--quick]
 """
@@ -12,6 +13,7 @@ import json
 import os
 import sys
 import time
+import traceback
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -20,7 +22,7 @@ from benchmarks import kernels_bench, paper_figs, roofline  # noqa: E402
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args, _ = ap.parse_known_args()
@@ -39,16 +41,18 @@ def main() -> None:
         ("roofline", roofline.run),
     ]
     os.makedirs(ART, exist_ok=True)
-    results = {}
+    results, failed = {}, []
     print("name,us_per_call,derived")
     for name, fn in benches:
         t0 = time.time()
         try:
             res = fn(quick=quick)
             ok = True
-        except Exception as e:  # noqa
+        except Exception as e:  # noqa: BLE001 - report, run the rest, fail
+            traceback.print_exc()
             res = {"error": f"{type(e).__name__}: {e}"}
             ok = False
+            failed.append(name)
         us = (time.time() - t0) * 1e6
         results[name] = res
         derived = _headline(name, res) if ok else res["error"]
@@ -57,6 +61,9 @@ def main() -> None:
     with open(os.path.join(ART, "bench_results.json"), "w") as f:
         json.dump(results, f, indent=1, default=float)
     print(f"# wrote {os.path.join(ART, 'bench_results.json')}")
+    if failed:
+        print(f"# FAILED: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 def _headline(name: str, res) -> str:
@@ -106,4 +113,4 @@ def _headline(name: str, res) -> str:
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

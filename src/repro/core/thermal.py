@@ -52,6 +52,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import resolve_interpret
+
 
 @dataclass(frozen=True)
 class ThermalConfig:
@@ -160,8 +162,8 @@ def _plan_levels(m: int, n: int, g_v: float, g_lat: float,
 
 
 def _use_pallas(tc: ThermalConfig) -> bool:
-    if tc.backend == "auto":
-        return jax.default_backend() == "tpu"
+    if tc.backend == "auto":  # the compiled kernel where there is one
+        return not resolve_interpret(None)
     return tc.backend == "pallas"
 
 

@@ -26,6 +26,7 @@ negligible next to the matmul).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +34,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.overscale_matmul import BK, BM, BN
+from repro.kernels import resolve_interpret
+from repro.kernels.overscale_matmul import BK, BM, BN, CDF_SPEC, inject_flips
 
 _LANE = 128   # lane tile carrying the broadcast row checksums
 _SUB = 8      # sublane tile carrying the broadcast column checksums
@@ -47,26 +49,14 @@ def _kernel(a_ref, b_ref, gate_ref, bit_ref, cdf_ref, c_ref, rs_ref, cs_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 x int8 -> int32 on the MXU (exact: no int32 x int32 matmul)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _finalize():
-        acc = acc_ref[...]
-        gate = gate_ref[...]  # uint32
-        ubit = bit_ref[...]  # uint32
-        cdf = cdf_ref[...]  # (33,) float32
-        p_total = cdf[-1]
-        u = gate.astype(jnp.float32) * (1.0 / 4294967296.0)
-        flip = u < p_total
-        u2 = ubit.astype(jnp.float32) * (1.0 / 4294967296.0) * p_total
-        bit_idx = jnp.sum(
-            (u2[..., None] >= cdf[None, None, 1:]).astype(jnp.int32), axis=-1)
-        bit_idx = jnp.clip(bit_idx, 0, 31)
-        mask = jnp.where(flip, jnp.left_shift(jnp.int32(1), bit_idx), 0)
-        c = jax.lax.bitwise_xor(acc, mask)
+        c = inject_flips(acc_ref[...], gate_ref[...], bit_ref[...], cdf_ref)
         c_ref[...] = c
         # fused checksums OF THE CORRUPTED PRODUCT: the syndromes vs the
         # protected references localize exactly the injected flips
@@ -77,10 +67,12 @@ def _kernel(a_ref, b_ref, gate_ref, bit_ref, cdf_ref, c_ref, rs_ref, cs_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def abft_matmul(a, b, u_gate, u_bit, cdf, *, interpret: bool = True):
+def abft_matmul(a, b, u_gate, u_bit, cdf, *,
+                interpret: Optional[bool] = None):
     """a:(M,K) int8, b:(K,N) int8, u_gate/u_bit:(M,N) uint32, cdf:(33,)
     float32 -> (c:(M,N) int32 with injected errors, rowsum:(M,) int32,
     colsum:(N,) int32) — checksums of the corrupted product."""
+    interpret = resolve_interpret(interpret)
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
@@ -103,7 +95,7 @@ def abft_matmul(a, b, u_gate, u_bit, cdf, *, interpret: bool = True):
             pl.BlockSpec((BK, BN), lambda i, j, k: (k, j)),
             pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
             pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
-            pl.BlockSpec((33,), lambda i, j, k: (0,)),
+            CDF_SPEC,
         ],
         out_specs=[
             pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),

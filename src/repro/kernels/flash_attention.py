@@ -13,11 +13,14 @@ D in {64, 128, 256}; the (BQ, BK) score tile stays in registers/VMEM and the
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -64,8 +67,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: Optional[bool] = None):
     """q:(S,D), k/v:(T,D) -> (S,D). vmap for batch/heads."""
+    interpret = resolve_interpret(interpret)
     S, D = q.shape
     T = k.shape[0]
     bq = min(bq, S)

@@ -13,11 +13,14 @@ assigned mamba2-780m (Q=256, H=48, P=64, N=128) the chunk working set is
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, state_ref, *,
@@ -57,11 +60,13 @@ def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, state_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def mamba_scan(xh, dt, A, B, C, *, chunk: int = 256, interpret: bool = True):
+def mamba_scan(xh, dt, A, B, C, *, chunk: int = 256,
+               interpret: Optional[bool] = None):
     """Single-batch SSD scan. xh:(S,H,P) dt:(S,H) A:(H,) B,C:(S,H,N) -> y.
 
     vmap over batch. Returns y:(S,H,P) (fp32 math, xh.dtype out).
     """
+    interpret = resolve_interpret(interpret)
     S, H, P = xh.shape
     N = B.shape[-1]
     q = min(chunk, S)

@@ -1,9 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``INTERPRET = None`` (the default) auto-detects per call: compiled on a TPU
-backend, interpreter everywhere else (interpret mode executes the kernel
-body for correctness validation on CPU). Set ``repro.kernels.ops.INTERPRET``
-to True/False to force a mode.
+Every kernel picks its mode through :func:`repro.kernels.resolve_interpret`:
+compiled on a TPU backend, the interpreter on the CPU backend (interpret
+mode executes the kernel body for correctness validation).
 """
 from __future__ import annotations
 
@@ -20,19 +19,11 @@ from repro.kernels.overscale_matmul import (bit_probs_to_cdf,
 from repro.kernels.paged_attention import paged_attention as _paged
 from repro.kernels.thermal_stencil import thermal_stencil as _stencil
 
-INTERPRET = None  # None = auto (compiled on TPU, interpreter elsewhere)
-
-
-def _interpret() -> bool:
-    return (jax.default_backend() != "tpu" if INTERPRET is None
-            else INTERPRET)
-
 
 def flash_attention_bh(q, k, v, *, causal=True, bq=128, bk=128):
     """Batched/multi-head wrapper: q:(B,S,H,D), k/v:(B,T,H,D)."""
     def one(q1, k1, v1):
-        return _flash(q1, k1, v1, causal=causal, bq=bq, bk=bk,
-                      interpret=_interpret())
+        return _flash(q1, k1, v1, causal=causal, bq=bq, bk=bk)
 
     return jax.vmap(jax.vmap(one, in_axes=(1, 1, 1), out_axes=1))(q, k, v)
 
@@ -42,27 +33,27 @@ def paged_attention_decode(q, k_pool, v_pool, ids_pool, block_table, pos, *,
     """Paged single-token decode: q:(B,H,D), pools:(P,ps,Hkv,D)/(P,ps),
     block_table:(B,n_pages) physical page ids, pos:(B,) query positions."""
     return _paged(q, k_pool, v_pool, ids_pool, block_table, pos,
-                  window=window, interpret=_interpret())
+                  window=window)
 
 
 def mamba_scan_b(xh, dt, A, B, C, *, chunk=256):
     """Batched wrapper: xh:(b,S,H,P), dt:(b,S,H), B/C:(b,S,H,N)."""
     def one(x1, d1, b1, c1):
-        return _mamba(x1, d1, A, b1, c1, chunk=chunk, interpret=_interpret())
+        return _mamba(x1, d1, A, b1, c1, chunk=chunk)
 
     return jax.vmap(one)(xh, dt, B, C)
 
 
 def thermal_sweep(T, P, diag, *, g_lat, g_v_tamb, iters=64, phase=None):
     return _stencil(T, P, diag, g_lat=g_lat, g_v_tamb=g_v_tamb, iters=iters,
-                    phase=phase, interpret=_interpret())
+                    phase=phase)
 
 
 def overscale_mm(a, b, u_gate, u_bit, cdf):
-    return _omm(a, b, u_gate, u_bit, cdf, interpret=_interpret())
+    return _omm(a, b, u_gate, u_bit, cdf)
 
 
 def abft_mm(a, b, u_gate, u_bit, cdf):
     """Error-injected int8 matmul with fused row/column checksums:
     -> (c, rowsum, colsum)."""
-    return _abft(a, b, u_gate, u_bit, cdf, interpret=_interpret())
+    return _abft(a, b, u_gate, u_bit, cdf)

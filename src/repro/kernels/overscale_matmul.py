@@ -12,7 +12,10 @@ distribution. Randomness enters as two uint32 planes (u_gate, u_bit) generated
 outside (keeps the kernel deterministic and oracle-checkable).
 
 BlockSpec tiling: (BM x BK) x (BK x BN) MXU-aligned blocks, K-major grid with
-an int32 VMEM accumulator scratch (revisited output block pattern).
+an int32 VMEM accumulator scratch (revisited output block pattern).  The
+(33,) cdf sits whole in SMEM and is read as scalars: the bit lookup is a
+static loop over the 32 thresholds, which the TPU lowering accepts where a
+vector slice of a 1-D block is refused.
 """
 from __future__ import annotations
 
@@ -25,7 +28,40 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 BM, BN, BK = 128, 128, 128
+CDF_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (33,) cdf
+
+
+def _u32_to_f32(x):
+    """uint32 -> float32 rounded once to nearest, as ``astype`` rounds; the
+    TPU kernel lowering has no unsigned convert.  Both 16-bit halves are
+    exact in float32, so ``hi * 2^16 + lo`` rounds only in the sum."""
+    i = jax.lax.bitcast_convert_type(x, jnp.int32)
+    hi = jax.lax.shift_right_logical(i, 16).astype(jnp.float32)
+    lo = (i & 0xFFFF).astype(jnp.float32)
+    return hi * 65536.0 + lo
+
+
+def inject_flips(acc, gate, ubit, cdf_ref):
+    """Flip one bit of each gated accumulator: ``acc`` (int32), ``gate`` /
+    ``ubit`` (uint32 uniforms), ``cdf_ref`` the (33,) SMEM cdf
+    ``[0, cdf..., p_total]``.  Same arithmetic as
+    ``ref.overscale_matmul_ref``, so the result is bitwise equal to it."""
+    p_total = cdf_ref[32]
+    # flip gate: u < p_total (u uniform in [0,1))
+    u = _u32_to_f32(gate) * (1.0 / 4294967296.0)
+    flip = u < p_total
+    # bit index: inverse-cdf lookup of second uniform scaled to p_total —
+    # the number of cumulative per-bit probabilities cdf[1:33] <= u2
+    u2 = _u32_to_f32(ubit) * (1.0 / 4294967296.0) * p_total
+    bit_idx = jnp.zeros(acc.shape, jnp.int32)
+    for t in range(1, 33):
+        bit_idx += (u2 >= cdf_ref[t]).astype(jnp.int32)
+    bit_idx = jnp.clip(bit_idx, 0, 31)
+    mask = jnp.where(flip, jnp.left_shift(jnp.int32(1), bit_idx), 0)
+    return jax.lax.bitwise_xor(acc, mask)
 
 
 def _kernel(a_ref, b_ref, gate_ref, bit_ref, cdf_ref, c_ref, acc_ref, *,
@@ -36,35 +72,23 @@ def _kernel(a_ref, b_ref, gate_ref, bit_ref, cdf_ref, c_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 x int8 -> int32 on the MXU (exact: no int32 x int32 matmul)
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _finalize():
-        acc = acc_ref[...]
-        gate = gate_ref[...]  # uint32
-        ubit = bit_ref[...]  # uint32
-        cdf = cdf_ref[...]  # (33,) float32: [0, cdf..., p_total at end]
-        p_total = cdf[-1]
-        # flip gate: u < p_total (u uniform in [0,1))
-        u = gate.astype(jnp.float32) * (1.0 / 4294967296.0)
-        flip = u < p_total
-        # bit index: inverse-cdf lookup of second uniform scaled to p_total
-        u2 = ubit.astype(jnp.float32) * (1.0 / 4294967296.0) * p_total
-        # cdf[1:33] are cumulative probs per bit; count how many are < u2
-        bit_idx = jnp.sum(
-            (u2[..., None] >= cdf[None, None, 1:]).astype(jnp.int32), axis=-1)
-        bit_idx = jnp.clip(bit_idx, 0, 31)
-        mask = jnp.where(flip, jnp.left_shift(jnp.int32(1), bit_idx), 0)
-        c_ref[...] = jax.lax.bitwise_xor(acc, mask)
+        c_ref[...] = inject_flips(acc_ref[...], gate_ref[...], bit_ref[...],
+                                  cdf_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def overscale_matmul(a, b, u_gate, u_bit, cdf, *, interpret: bool = True):
+def overscale_matmul(a, b, u_gate, u_bit, cdf, *,
+                     interpret: Optional[bool] = None):
     """a:(M,K) int8, b:(K,N) int8, u_gate/u_bit:(M,N) uint32,
     cdf:(33,) float32 -> (M,N) int32 with injected errors."""
+    interpret = resolve_interpret(interpret)
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
@@ -83,7 +107,7 @@ def overscale_matmul(a, b, u_gate, u_bit, cdf, *, interpret: bool = True):
             pl.BlockSpec((BK, BN), lambda i, j, k: (k, j)),
             pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
             pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
-            pl.BlockSpec((33,), lambda i, j, k: (0,)),
+            CDF_SPEC,
         ],
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
@@ -109,7 +133,7 @@ def quantize(x, bits: int = 8):
 
 def make_int8_error_matmul(bit_probs, key, use_pallas: bool = False):
     """Returns matmul(a_f32, b_f32) -> f32 that quantizes, runs the
-    error-injected int8 matmul (ref by default; pallas-interpret opt-in),
+    error-injected int8 matmul (ref by default; the Pallas kernel opt-in),
     and dequantizes with clipping (the fixed-point requantization step)."""
     from repro.kernels import ref as kref
     cdf = bit_probs_to_cdf(bit_probs)

@@ -25,11 +25,14 @@ dot_general — no vmap over heads, one pallas_call per batch.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +85,7 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, ids_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_attention(q, k_pool, v_pool, ids_pool, block_table, pos, *,
-                    window: int = 0, interpret: bool = True):
+                    window: int = 0, interpret: Optional[bool] = None):
     """Paged single-token decode attention.
 
     q:(B,H,D), k/v pool:(P,ps,Hkv,D), ids pool:(P,ps) int32,
@@ -90,6 +93,7 @@ def paged_attention(q, k_pool, v_pool, ids_pool, block_table, pos, *,
     positions (-1 disables a row -> zero output).  ``window`` > 0 adds the
     sliding-window bound.  Returns (B,H,D).
     """
+    interpret = resolve_interpret(interpret)
     B, H, D = q.shape
     P, ps, Hkv, _ = k_pool.shape
     n_pages = block_table.shape[1]

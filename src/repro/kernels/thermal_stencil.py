@@ -17,9 +17,6 @@ Two sweep flavours share the kernel body:
   is what gives RB-GS its 2x Jacobi smoothing rate; the colour masks are
   2D ``broadcasted_iota`` parities, which lower to vector ops on TPU.
 
-``interpret`` defaults to auto-detection: compiled on a TPU backend,
-interpreter everywhere else (the kwarg remains an explicit override).
-
 Block layout: grid=(), whole-array BlockSpecs in VMEM; the neighbour sum is
 computed with in-kernel shifts (jnp.pad/slice lower to vector ops on TPU).
 """
@@ -31,7 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(T_ref, P_ref, diag_ref, o_ref, *, g_lat: float, g_v_tamb: float,
@@ -72,11 +70,10 @@ def thermal_stencil(T, P, diag, *, g_lat: float, g_v_tamb: float,
     """K fused sweeps. T,P,diag: (m,n) fp32 -> (m,n) fp32.
 
     ``phase=None`` runs Jacobi sweeps; ``phase=0|1`` runs red-black
-    Gauss-Seidel sweeps starting on that colour.  ``interpret=None``
-    auto-selects: compiled on TPU, interpreter elsewhere.
+    Gauss-Seidel sweeps starting on that colour.  ``interpret`` follows
+    :func:`repro.kernels.resolve_interpret`.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     m, n = T.shape
     spec = pl.BlockSpec((m, n), lambda: (0, 0))
     return pl.pallas_call(

@@ -18,6 +18,7 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 _DIGITS = re.compile(r"\d+")
 # host/worker composition only applies to names that really carry BOTH
@@ -26,17 +27,24 @@ _DIGITS = re.compile(r"\d+")
 _HOST_WORKER = re.compile(r"host(\d+).*?worker(\d+)")
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the plan places arrays with
+    ``NamedSharding``s and the compiler propagates the rest (``make_mesh``
+    alone gives ``Explicit`` axes, which refuse reshapes of sharded dims)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over the actually-available local devices (tests/examples)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 @dataclass(frozen=True)

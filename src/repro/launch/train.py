@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import argparse
 import time
-from typing import Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from repro.data.pipeline import DataConfig, make_iterator
 from repro.ft.elastic import ElasticActuator, ElasticWorkAssignment
 from repro.ft.monitor import (FailureInjector, StragglerDetector,
                               TransientError, retry_step)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import PodTopology, make_host_mesh
 from repro.models import params as pm
 from repro.models.model import Model
@@ -44,27 +45,34 @@ from repro.train.optimizer import make_optimizer
 from repro.train.step import make_train_step
 
 
-def build(arch: str, smoke: bool, mesh, batch: int, seq: int, n_accum: int):
-    cfg = registry.get(arch)
-    if smoke:
-        cfg = cfg.reduced()
+def build(cfg, mesh, n_accum: int = 1):
+    """Wire ``cfg`` onto ``mesh``: plan, model, optimizer, the jitted
+    train step (plan shardings, params and optimizer state donated), and
+    ``init(key) -> (params, opt_state)``, which materializes both directly
+    in their shardings (never whole on one device)."""
     plan = make_plan(cfg, mesh)
     model = Model(cfg, plan)
     opt = make_optimizer(cfg, total_steps=10_000)
     step_fn = make_train_step(model, opt, n_accum=n_accum)
     meta = model.param_meta()
 
-    in_sh = (plan.param_shardings(meta),
-             jax.tree_util.tree_map(
-                 lambda s: NamedSharding(mesh, s),
-                 plan.param_specs(opt.state_meta(meta)),
-                 is_leaf=lambda x: isinstance(x, P)),
-             None, None)
-    jit_step = jax.jit(step_fn, in_shardings=in_sh, donate_argnums=(0, 1))
-    return cfg, plan, model, opt, jit_step
+    param_sh = plan.param_shardings(meta)
+    opt_sh = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s),
+        plan.param_specs(opt.state_meta(meta)),
+        is_leaf=lambda x: isinstance(x, P))
+    jit_step = jax.jit(step_fn, in_shardings=(param_sh, opt_sh, None, None),
+                       donate_argnums=(0, 1))
+
+    def init(key):
+        params = jax.jit(model.init, out_shardings=param_sh)(key)
+        return params, jax.jit(opt.init, out_shardings=opt_sh)(params)
+
+    return plan, model, opt, jit_step, init
 
 
-def main(argv=None):
+def main(argv=None) -> List[float]:
+    """Train from the command line; returns each step's loss."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true", default=True)
@@ -84,17 +92,17 @@ def main(argv=None):
                     help="ambient degC the control plane senses")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     mesh = make_host_mesh(model=args.model_parallel)
-    cfg, plan, model, opt, jit_step = build(
-        args.arch, args.smoke, mesh, args.batch, args.seq, args.n_accum)
+    cfg = registry.get(args.arch)
+    if args.smoke:
+        cfg = cfg.reduced()
+    plan, model, opt, jit_step, init = build(cfg, mesh, args.n_accum)
     print(f"[train] arch={cfg.name} params={model.n_params():,} "
           f"mesh={dict(mesh.shape)}")
 
-    key = jax.random.PRNGKey(0)
-    with mesh:
-        params = model.init(key)
-        opt_state = opt.init(params)
+    params, opt_state = init(jax.random.PRNGKey(0))
 
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                     global_batch=args.batch)
@@ -139,6 +147,7 @@ def main(argv=None):
             controller, [fleet, elastic])
 
     step = start_step
+    losses = []
     t_train0 = time.time()
     while step < args.steps:
         batch = next(it)
@@ -152,7 +161,7 @@ def main(argv=None):
 
         t0 = time.time()
         params, opt_state, metrics = retry_step(do_step, on_failure=on_fail)
-        jax.block_until_ready(metrics["loss"])
+        losses.append(float(metrics["loss"]))
         dt = time.time() - t0
         ev = straggler.record("worker0", step, dt)
         if ev:
@@ -192,8 +201,8 @@ def main(argv=None):
         ckpt.wait()
     print(f"[train] done: {args.steps - start_step} steps in "
           f"{time.time() - t_train0:.1f}s; final loss "
-          f"{float(metrics['loss']):.4f}")
-    return float(metrics["loss"])
+          f"{losses[-1]:.4f}")
+    return losses
 
 
 if __name__ == "__main__":

@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -67,8 +66,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh,
 
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                 P())
-    fn = shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                       check_vma=False)
     xs = x.reshape((n_microbatches, mb) + x.shape[1:])
     outs = fn(stage_params, xs)
     return outs.reshape(x.shape)

@@ -7,8 +7,8 @@ repairs what the syndromes localize:
 
 - an XOR flip of bit b in element (i, j) shifts ``rowsum[i]`` and
   ``colsum[j]`` by the same delta (mod 2^32) — a matching nonzero pair
-  ``dr[i] == dc[j]`` pinpoints the cell, and subtracting the delta restores
-  it exactly;
+  ``dr[i] == dc[j]`` names the cell, and subtracting the delta restores it
+  exactly once the cell's own dot product confirms it;
 - multiple flips sharing a row/column alias: their syndromes are detected
   but not uniquely localizable — those remain as *escapes* (the residue the
   ``ErrorTolerant`` accuracy budget is declared against).
@@ -54,11 +54,21 @@ class AbftCounters:
         return self.escaped / self.checked if self.checked else 0.0
 
 
-def detect_and_correct(c, rowsum, colsum, row_ref, col_ref
+def detect_and_correct(c, rowsum, colsum, a, b
                        ) -> Tuple[np.ndarray, int, int]:
-    """Repair uniquely-localized single flips; return (c_fixed, detected,
-    corrected).  All int32, arithmetic wrapping mod 2^32 on both sides of
-    every syndrome."""
+    """Repair localized flips of ``c`` ~ ``a @ b``; return (c_fixed,
+    detected, corrected).  ``rowsum``/``colsum`` are the checksums of ``c``;
+    the protected references come from the int8 inputs ``a``, ``b``.  All
+    int32, arithmetic wrapping mod 2^32 on both sides of every syndrome.
+
+    A row syndrome ``dr[i]`` that matches exactly one column syndrome
+    ``dc[j]`` (and the other way round) names cell (i, j).  A lone flip
+    there gives that pair — but so do two flips at (i, j') and (i', j) with
+    equal deltas whose row i' and column j' hold further flips.  So a named
+    cell is repaired only if subtracting the syndrome restores its own dot
+    product ``a[i] . b[:, j]``: a correction never touches a healthy cell.
+    """
+    row_ref, col_ref = checksum_refs(a, b)
     c = np.asarray(c, np.int32).copy()
     dr = np.subtract(np.asarray(rowsum, np.int32),
                      np.asarray(row_ref, np.int32), dtype=np.int32)
@@ -74,8 +84,13 @@ def detect_and_correct(c, rowsum, colsum, row_ref, col_ref
     # a healthy cell
     fix = (match & (match.sum(axis=1) == 1)[:, None]
            & (match.sum(axis=0) == 1)[None, :])
-    c -= np.where(fix, dr[:, None], np.int32(0)).astype(np.int32)
-    return c, detected, int(fix.sum())
+    ii, jj = np.nonzero(fix)
+    repaired = np.subtract(c[ii, jj], dr[ii], dtype=np.int32)
+    dots = np.einsum("nk,kn->n", np.asarray(a, np.int64)[ii],
+                     np.asarray(b, np.int64)[:, jj]).astype(np.int32)
+    ok = repaired == dots
+    c[ii[ok], jj[ok]] = repaired[ok]
+    return c, detected, int(ok.sum())
 
 
 class AbftMatmul:
@@ -84,7 +99,8 @@ class AbftMatmul:
     Mirrors ``make_int8_error_matmul`` (quantize -> inject -> requantize
     with calibrated clipping) with the detect/correct pass in between and
     a :class:`AbftCounters` ledger on the side.  ``use_pallas`` selects the
-    fused Pallas kernel (interpret mode off-TPU) over the jnp oracle.
+    fused Pallas kernel (compiled on a TPU, interpreted on the CPU) over the
+    jnp oracle.
     """
 
     def __init__(self, bit_probs, key, use_pallas: bool = False):
@@ -106,9 +122,7 @@ class AbftMatmul:
             c, rs, cs = abft_matmul(qa, qb, u_gate, u_bit, self.cdf)
         else:
             c, rs, cs = kref.abft_matmul_ref(qa, qb, u_gate, u_bit, self.cdf)
-        row_ref, col_ref = checksum_refs(qa, qb)
-        fixed, detected, corrected = detect_and_correct(
-            c, rs, cs, row_ref, col_ref)
+        fixed, detected, corrected = detect_and_correct(c, rs, cs, qa, qb)
         # simulation ground truth: the clean product (already needed for
         # the requantization clip limit) exposes injections and escapes
         clean = np.asarray(jax.lax.dot_general(

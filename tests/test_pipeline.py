@@ -41,6 +41,7 @@ print("PIPELINE_OK")
 def test_gpipe_schedule_matches_sequential():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # host devices; never a second chip user
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "PIPELINE_OK" in out.stdout, (out.stdout[-500:], out.stderr[-2000:])

@@ -101,7 +101,8 @@ from repro.sharding.plan import make_plan
 from repro.train.optimizer import make_optimizer
 from repro.train.step import make_train_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = registry.get("llama3.2-1b").reduced().replace(
     num_heads=4, num_kv_heads=2, head_dim=16, d_model=64, d_ff=128)
 plan = make_plan(cfg, mesh)
@@ -133,6 +134,7 @@ def test_mini_mesh_train_step_subprocess():
     """Real 8-device SPMD train step (subprocess keeps this process at 1)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # host devices; never a second chip user
     out = subprocess.run([sys.executable, "-c", MINI_DRYRUN], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "MINI_DRYRUN_OK" in out.stdout, out.stderr[-2000:]

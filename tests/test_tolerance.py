@@ -269,9 +269,7 @@ class TestDetectAndCorrect:
         bad = clean.copy()
         bad[3, 5] += np.int32(1 << 20)  # one carry-tail flip
         rs, cs = self._sums(bad)
-        row_ref, col_ref = checksum_refs(a, b)
-        fixed, detected, corrected = detect_and_correct(
-            bad, rs, cs, row_ref, col_ref)
+        fixed, detected, corrected = detect_and_correct(bad, rs, cs, a, b)
         assert detected == 1 and corrected == 1
         np.testing.assert_array_equal(fixed, clean)
 
@@ -281,9 +279,7 @@ class TestDetectAndCorrect:
         bad[1, 2] += np.int32(1 << 18)
         bad[7, 9] -= np.int32(1 << 22)  # distinct rows, cols AND deltas
         rs, cs = self._sums(bad)
-        row_ref, col_ref = checksum_refs(a, b)
-        fixed, detected, corrected = detect_and_correct(
-            bad, rs, cs, row_ref, col_ref)
+        fixed, detected, corrected = detect_and_correct(bad, rs, cs, a, b)
         assert detected == 2 and corrected == 2
         np.testing.assert_array_equal(fixed, clean)
 
@@ -295,9 +291,7 @@ class TestDetectAndCorrect:
         bad[3, 5] += np.int32(1 << 20)
         bad[3, 9] += np.int32(1 << 20)
         rs, cs = self._sums(bad)
-        row_ref, col_ref = checksum_refs(a, b)
-        fixed, detected, corrected = detect_and_correct(
-            bad, rs, cs, row_ref, col_ref)
+        fixed, detected, corrected = detect_and_correct(bad, rs, cs, a, b)
         assert detected == 2
         assert corrected == 0  # no healthy cell was "repaired"
         assert np.count_nonzero(fixed != clean) == 2  # the escapes
@@ -310,9 +304,7 @@ class TestDetectAndCorrect:
         bad[2, 4] += np.int32(1 << 19)
         bad[6, 8] += np.int32(1 << 19)
         rs, cs = self._sums(bad)
-        row_ref, col_ref = checksum_refs(a, b)
-        fixed, detected, corrected = detect_and_correct(
-            bad, rs, cs, row_ref, col_ref)
+        fixed, detected, corrected = detect_and_correct(bad, rs, cs, a, b)
         assert detected == 2 and corrected == 0
         np.testing.assert_array_equal(fixed != clean, bad != clean)
 
