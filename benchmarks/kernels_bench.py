@@ -215,18 +215,17 @@ def closed_loop(quick: bool = True) -> Dict:
         for _ in range(4):
             e.step()  # feed prompts; all slots now mid-decode
         plan, _ = e._compose()
-        key = jax.random.PRNGKey(0)
-        e._run_fused(e._fused, plan, key)
-        return e, plan, key
+        e._run_fused(e._fused, plan)
+        return e, plan
 
     pair = {False: _steady(False), True: _steady(True)}
     best = {False: float("inf"), True: float("inf")}
     iters = 20
     for _ in range(9):
-        for paged, (e, plan, key) in pair.items():
+        for paged, (e, plan) in pair.items():
             t0 = time.perf_counter()
             for _ in range(iters):
-                e._run_fused(e._fused, plan, key)
+                e._run_fused(e._fused, plan)
             best[paged] = min(best[paged],
                               (time.perf_counter() - t0) / iters)
     out["contig_decode_us"] = best[False] * 1e6

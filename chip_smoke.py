@@ -25,8 +25,10 @@ with: 3 steps of the whole 28-layer model through
 ``repro.launch.train.main`` on a (4, 1) mesh, and one step of a 2-layer cut
 on one device and on four, whose loss and grad norm must agree.
 
-Each phase prints one line: its checks, wall and compile seconds, and the
-device's ``peak_bytes_in_use`` so far.  The last line is the result,
+Each phase prints one line: its checks, wall and compile seconds (JAX's
+compiles and persistent-cache loads, counted by the benchmark's
+``benchmarks.chip.harness.CompileClock``), and the device's
+``peak_bytes_in_use`` so far.  The last line is the result,
 ``{"ok": true, "device": {...}}``, printed only when every phase passed.
 Without a TPU, or with any phase failing, the script exits non-zero and
 prints no result.  The compile cache goes where
@@ -50,22 +52,10 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from benchmarks.chip.harness import CompileClock  # noqa: E402
+
 ARCH = "qwen3-1.7b"
 BF16_ULP = 2.0 ** -7  # spacing of bfloat16 values in [1, 2)
-
-
-class CompileClock:
-    """Seconds spent in XLA backend compiles (JAX's monitoring events)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == self.EVENT:
-            self.total += duration
 
 
 def _peak_bytes(device=None):
@@ -316,16 +306,19 @@ def phase_fsdp_vs_single(cfg, seed, batch=8, seq=512, rtol=2 * BF16_ULP):
 # --- driver ----------------------------------------------------------------
 
 def run_phase(name, fn, clock):
-    t0, c0 = time.perf_counter(), clock.total
+    t0 = time.perf_counter()
     try:
         ok, check = fn()
     except Exception as e:  # noqa: BLE001 - report the phase, run the rest
         traceback.print_exc()
         ok, check = False, f"raised {type(e).__name__}: {e}"
     gc.collect()
+    ev = clock.counts(since=t0)
     print(f"[{name}] {'PASS' if ok else 'FAIL'} {check} | wall "
           f"{time.perf_counter() - t0:.1f} s, compile "
-          f"{clock.total - c0:.1f} s, peak_bytes_in_use {_peak_bytes()}",
+          f"{ev['compiles'][1]:.1f} s ({ev['compiles'][0]} compiles, "
+          f"{ev['cache_loads'][0]} of them cache loads; {ev['traces'][0]} "
+          f"traces), peak_bytes_in_use {_peak_bytes()}",
           flush=True)
     return ok
 

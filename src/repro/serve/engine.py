@@ -44,6 +44,20 @@ Two scheduling paths, picked by model family:
 Control-plane hooks (repro.control, DESIGN.md §3): EVERY ``step()`` emits a
 ``TickSample`` — including admit-only and fully-throttled iterations, so
 queue-depth bursts are visible exactly when ``Throttle`` decisions matter.
+
+Tracing: each tick's host phases are profiler spans
+(``jax.profiler.TraceAnnotation``, nearly free while no trace is taken),
+so they land on the same clock as the device's operations:
+``serve.engine.admit``, ``.compose`` (plan, page reservation, preemption),
+``.upload`` (key split, tick arrays, block table; stat ``bt_sent``),
+``.dispatch`` (the fused call; stats ``width``, ``prefill``, ``decode``,
+``prompt_tokens``), ``.sync`` (the host copy of the output), ``.commit``
+(advance and append) and ``.release`` (pages returned, stat ``pages``;
+nested in ``commit``, or in ``compose`` when preemption frees a slot).
+Inside the fused step, ``jax.named_scope`` marks ``kv_gather``,
+``decode``, ``sample`` and ``kv_scatter`` in every device op's metadata.
+Each :class:`Request` carries ``perf_counter`` stamps of its submission,
+first admission, first token and completion.
 """
 from __future__ import annotations
 
@@ -54,6 +68,7 @@ from typing import Callable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.control.telemetry import TickSample
 from repro.models.model import Model
@@ -77,6 +92,12 @@ class Request:
     submit_tick: int = 0  # engine tick at submission (queue-age / SLO)
     finish_tick: int = 0
     preempts: int = 0     # times evicted to the host page pool
+    # time.perf_counter() stamps: submit(), first slot assignment (a resume
+    # after preemption keeps it), first token appended, completion
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
 
 
 class Engine:
@@ -152,39 +173,53 @@ class Engine:
         self.on_tick: List[Callable[[TickSample], None]] = []
         self.ticks = 0
 
+        def decode(params, tokens, cache, pos, n_valid):
+            with jax.named_scope("decode"):
+                return model.decode(params, tokens, cache, pos,
+                                     n_valid=n_valid)
+
+        def pick(logits, n_valid, key):
+            """Each slot's next token, from the logit after its last valid
+            input."""
+            with jax.named_scope("sample"):
+                idx = jnp.clip(n_valid - 1, 0, logits.shape[1] - 1)
+                last = jnp.take_along_axis(
+                    logits, idx[:, None, None], axis=1)[:, 0]  # (B,V)
+                return sample(last, key, self.temperature, self.top_k)
+
+        def verify_rows(logits):
+            # every row's greedy continuation — the verify step
+            with jax.named_scope("sample"):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
         if self._paged:
             mgr = self.mgr
 
+            def gather(pool, bt):
+                with jax.named_scope("kv_gather"):
+                    return mgr.gather_logical(pool, bt)
+
+            def scatter(pool, cache, inv):
+                with jax.named_scope("kv_scatter"):
+                    return mgr.scatter_all(pool, cache, inv)
+
             def fused(params, pool, bt, inv, tokens, pos, n_valid, key):
-                cache = mgr.gather_logical(pool, bt)
-                logits, cache = model.decode(params, tokens, cache, pos,
-                                             n_valid=n_valid)
-                idx = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)
-                last = jnp.take_along_axis(
-                    logits, idx[:, None, None], axis=1)[:, 0]  # (B,V)
-                nxt = sample(last, key, self.temperature, self.top_k)
-                return nxt, mgr.scatter_all(pool, cache, inv)
+                logits, cache = decode(params, tokens, gather(pool, bt), pos,
+                                       n_valid)
+                return pick(logits, n_valid, key), scatter(pool, cache, inv)
 
             def fused_spec(params, pool, bt, inv, tokens, pos, n_valid, key):
-                cache = mgr.gather_logical(pool, bt)
-                logits, cache = model.decode(params, tokens, cache, pos,
-                                             n_valid=n_valid)
-                rows = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return rows, mgr.scatter_all(pool, cache, inv)
+                logits, cache = decode(params, tokens, gather(pool, bt), pos,
+                                       n_valid)
+                return verify_rows(logits), scatter(pool, cache, inv)
         else:
             def fused(params, cache, tokens, pos, n_valid, key):
-                logits, cache = model.decode(params, tokens, cache, pos,
-                                             n_valid=n_valid)
-                idx = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)
-                last = jnp.take_along_axis(
-                    logits, idx[:, None, None], axis=1)[:, 0]  # (B,V)
-                return sample(last, key, self.temperature, self.top_k), cache
+                logits, cache = decode(params, tokens, cache, pos, n_valid)
+                return pick(logits, n_valid, key), cache
 
             def fused_spec(params, cache, tokens, pos, n_valid, key):
-                logits, cache = model.decode(params, tokens, cache, pos,
-                                             n_valid=n_valid)
-                # every row's greedy continuation — the verify step
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+                logits, cache = decode(params, tokens, cache, pos, n_valid)
+                return verify_rows(logits), cache
 
         # the paged step donates the pool: the scatter then updates the
         # page buffers in place instead of copying the whole pool per
@@ -196,28 +231,42 @@ class Engine:
         if warmup:
             self._warmup()
 
-    def _run_fused(self, fn, plan: sched.TickPlan, key) -> np.ndarray:
+    def _run_fused(self, fn, plan: sched.TickPlan) -> np.ndarray:
         """One fused device step over the plan (gather -> decode -> scatter
-        on the paged path); returns the host copy of the sampled output."""
-        toks = jnp.asarray(plan.tokens)
-        pos = jnp.asarray(plan.pos)
-        nv = jnp.asarray(plan.n_valid)
-        if self._paged:
-            bt, inv = self._bt_device()
-            out, self.mgr.pool = fn(self.params, self.mgr.pool, bt, inv,
-                                    toks, pos, nv, key)
-        else:
-            out, self.mgr.cache = fn(self.params, self.mgr.cache,
-                                     toks, pos, nv, key)
-        return np.asarray(out)  # the tick's single host sync
+        on the paged path) under the upload, dispatch and sync spans;
+        returns the host copy of the sampled output."""
+        with span("serve.engine.upload",
+                  bt_sent=int(self._paged and self._bt_stale())):
+            self.key, key = jax.random.split(self.key)
+            toks = jnp.asarray(plan.tokens)
+            pos = jnp.asarray(plan.pos)
+            nv = jnp.asarray(plan.n_valid)
+            if self._paged:
+                bt, inv = self._bt_device()
+        prefill = [w for w in plan.work if w.kind == "prefill"]
+        with span("serve.engine.dispatch", width=plan.width,
+                  prefill=len(prefill), decode=len(plan.work) - len(prefill),
+                  prompt_tokens=sum(len(w.tokens) for w in prefill)):
+            if self._paged:
+                out, self.mgr.pool = fn(self.params, self.mgr.pool, bt, inv,
+                                        toks, pos, nv, key)
+            else:
+                out, self.mgr.cache = fn(self.params, self.mgr.cache,
+                                         toks, pos, nv, key)
+        with span("serve.engine.sync"):
+            return np.asarray(out)  # the tick's single host sync
+
+    def _bt_stale(self) -> bool:
+        """True when the host block table differs from its device copy."""
+        return self._bt_host is None or not np.array_equal(
+            self._bt_host, self.mgr.block_table)
 
     def _bt_device(self):
         """Device copies of the block table and its inverse page map,
         re-uploaded only when the host table actually changed (steady
         decode re-uses pages for page_size ticks at a time, so most ticks
         skip the transfer)."""
-        if self._bt_host is None or not np.array_equal(
-                self._bt_host, self.mgr.block_table):
+        if self._bt_stale():
             self._bt_host = self.mgr.block_table.copy()
             self._bt_dev = (jnp.asarray(self._bt_host, jnp.int32),
                             jnp.asarray(self.mgr.inverse_map(), jnp.int32))
@@ -255,6 +304,8 @@ class Engine:
 
     def submit(self, req: Request):
         req.submit_tick = self.ticks
+        if req.t_submit is None:  # a fleet resubmission keeps the first
+            req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     # -- admission ------------------------------------------------------------
@@ -290,10 +341,12 @@ class Engine:
                 req.done = True
                 req.error = "prompt_too_long"
                 req.finish_tick = self.ticks
+                req.t_done = time.perf_counter()
                 self.finished.append(req)
                 continue  # a reject is not an admission
             slot = self.mgr.allocate(len(req.prompt))
             self.slot_req[slot] = req
+            req.t_admit = time.perf_counter()
             req.fed = 0
             if not self._ragged:
                 self._prefill_into(slot, req)
@@ -329,7 +382,7 @@ class Engine:
                           pages=pages, owner=self.mgr, page_ids=page_ids,
                           freed=True)
             self.slot_req[slot] = None
-            self.mgr.free(slot)
+            self._release(slot)
             req.preempts += 1
             self.preempts += 1
             requeue.append(req)
@@ -428,10 +481,12 @@ class Engine:
                 w.slot, int(self.mgr.pos[w.slot]) + int(plan.n_valid[w.slot]))
         return True
 
-    def _tick(self) -> int:
+    def _plan(self) -> Tuple[Optional[sched.TickPlan], bool]:
+        """Compose the tick and reserve the pages its real tokens write,
+        preempting and recomposing while the free list falls short."""
         plan, spec = self._compose()
         if plan is None:
-            return 0
+            return None, False
         if self._paged:
             if isinstance(self.mgr, ExpandablePagedKVCacheManager):
                 self.mgr.ensure(int(plan.pos.max() + plan.width))
@@ -447,16 +502,29 @@ class Engine:
                 self.preempt_to(n_active - 1)
                 plan, spec = self._compose()
                 if plan is None:
-                    return 0
+                    return None, False
                 if isinstance(self.mgr, ExpandablePagedKVCacheManager):
                     self.mgr.ensure(int(plan.pos.max() + plan.width))
         elif isinstance(self.mgr, ExpandableKVCacheManager):
             self.mgr.ensure(int(plan.pos.max() + plan.width))
-        self.key, sk = jax.random.split(self.key)
+        return plan, spec
+
+    def _tick(self) -> int:
+        with span("serve.engine.compose"):
+            plan, spec = self._plan()
+        if plan is None:
+            return 0
         if spec:
-            rows = self._run_fused(self._fused_spec, plan, sk)  # (B, k+1)
-            return self._commit_spec(plan, rows)
-        nxt = self._run_fused(self._fused, plan, sk)
+            rows = self._run_fused(self._fused_spec, plan)  # (B, k+1)
+            with span("serve.engine.commit"):
+                return self._commit_spec(plan, rows)
+        nxt = self._run_fused(self._fused, plan)
+        with span("serve.engine.commit"):
+            return self._commit(plan, nxt)
+
+    def _commit(self, plan: sched.TickPlan, nxt: np.ndarray) -> int:
+        """Advance every slot the tick fed and append its sampled token
+        (a prefill slot only once its last prompt chunk is in)."""
         gen = 0
         self.mgr.advance([w.slot for w in plan.work],
                          [len(w.tokens) for w in plan.work])
@@ -500,9 +568,13 @@ class Engine:
                 # avoids a free/invalidate/realloc round trip per tick);
                 # stale entries in kept pages self-heal (pos_ids > every
                 # later query position until sequentially overwritten)
-                self.mgr.trim(w.slot, min(
-                    int(self.mgr.pos[w.slot]) + self._spec_k + 1,
-                    self.max_len))
+                upto = min(int(self.mgr.pos[w.slot]) + self._spec_k + 1,
+                           self.max_len)
+                freed = self.mgr.slot_pages(w.slot) - -(
+                    -upto // self.mgr.page_size)
+                if freed > 0:
+                    with span("serve.engine.release", pages=freed):
+                        self.mgr.trim(w.slot, upto)
         return gen
 
     @property
@@ -512,13 +584,21 @@ class Engine:
                 if self.spec_proposed else 0.0)
 
     def _append(self, req: Request, slot: int, tok: int):
+        if not req.out:
+            req.t_first = time.perf_counter()
         req.out.append(tok)
         if (tok == self.eos or len(req.out) >= req.max_new
                 or self.mgr.pos[slot] >= self.max_len - 1):
             req.done = True
             req.finish_tick = self.ticks
+            req.t_done = time.perf_counter()
             self.finished.append(req)
             self.slot_req[slot] = None
+            self._release(slot)
+
+    def _release(self, slot: int):
+        """Return a slot and its pages (the invalidation is a dispatch)."""
+        with span("serve.engine.release", pages=self.mgr.slot_pages(slot)):
             self.mgr.free(slot)
 
     # -- scheduler loop -------------------------------------------------------
@@ -529,7 +609,8 @@ class Engine:
         if not (self.queue or any(r is not None for r in self.slot_req)):
             return False
         t0 = time.perf_counter()
-        admitted = self._admit()
+        with span("serve.engine.admit"):
+            admitted = self._admit()
         gen = self._tick()
         oldest = (float(self.ticks - min(r.submit_tick for r in self.queue))
                   if self.queue else 0.0)
