@@ -297,12 +297,61 @@ def gqa_decode(p, x, cache, pos, cfg: ModelConfig, plan: Plan, n_valid=None):
         k = _row_update(cache["k"], k_new, start)
         v = _row_update(cache["v"], v_new, start)
         pos_ids = _row_update(cache["pos_ids"], ids, start)  # (B,T)
-        valid = (pos_ids >= 0)[:, None, :] & \
-            (pos_ids[:, None, :] <= positions[..., None])
-        mask = valid[:, None, None]  # (B,1,1,S,T)
-        o = _sdpa(q, k, v, mask, plan)
+        o = _attend_full(q, k, v, pos_ids, positions, plan)
     o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(x.dtype))
     return o, {"k": k, "v": v, "pos_ids": pos_ids}
+
+
+def _attend_full(q, k, v, pos_ids, positions, plan: Plan):
+    """Attention over a full-length cache (B,T,...) under the position-table
+    mask: query (b, s) sees entry t iff
+    ``0 <= pos_ids[b, t] <= positions[b, s]``."""
+    valid = (pos_ids >= 0)[:, None, :] & \
+        (pos_ids[:, None, :] <= positions[..., None])
+    return _sdpa(q, k, v, valid[:, None, None], plan)  # mask (B,1,1,S,T)
+
+
+def gqa_decode_paged(p, x, pool, layer, bt, pos, cfg: ModelConfig,
+                     plan: Plan, n_valid=None):
+    """Ragged decode/extend against a page pool, for full-length GQA caches.
+
+    ``pool`` holds every layer's pages: ``k``/``v`` (L, P, page, Hkv, D) and
+    ``pos_ids`` (L, P, page); ``bt`` (B, W) maps each row's logical page j
+    to a physical page.  Layer ``layer`` writes the chunk's new entries in
+    place (position p of row b lands on page ``bt[b, p // page]`` at offset
+    ``p % page``), then reads its pages through ``bt`` as a (B, W*page)
+    logical view and attends exactly as :func:`gqa_decode` does.  Entries
+    past ``n_valid`` and positions past ``W * page`` are never written.
+    """
+    B, S, _ = x.shape
+    q, k_new, v_new = _qkv(p, x, x, cfg, plan)
+    positions = decode_positions(pos, B, S)  # (B,S)
+    q = apply_rope(q, positions, cfg)
+    k_new = apply_rope(k_new, positions, cfg)
+    ids = _new_pos_ids(positions, n_valid)
+    n_pages, page = pool["pos_ids"].shape[1:]
+    W = bt.shape[1]
+    with jax.named_scope("kv_write"):
+        j = positions // page
+        keep = (ids >= 0) & (j < W)
+        dest = jnp.where(
+            keep, jnp.take_along_axis(bt, jnp.minimum(j, W - 1), axis=1),
+            n_pages)  # out of range: the scatter drops it
+        at = (layer, dest, positions % page)
+        pool = {
+            "k": pool["k"].at[at].set(k_new.astype(pool["k"].dtype),
+                                      mode="drop"),
+            "v": pool["v"].at[at].set(v_new.astype(pool["v"].dtype),
+                                      mode="drop"),
+            "pos_ids": pool["pos_ids"].at[at].set(ids, mode="drop"),
+        }
+    with jax.named_scope("kv_read"):
+        k = pool["k"][layer, bt].reshape((B, W * page) + pool["k"].shape[3:])
+        v = pool["v"][layer, bt].reshape((B, W * page) + pool["v"].shape[3:])
+        pos_ids = pool["pos_ids"][layer, bt].reshape(B, W * page)
+    o = _attend_full(q, k, v, pos_ids, positions, plan)
+    o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(x.dtype))
+    return o, pool
 
 
 def gqa_seed_cache(cache, kv, prefill_len: int, lengths=None):

@@ -87,6 +87,20 @@ class Model:
         return tf.lm_decode(params, tokens, cache, pos, cfg, plan,
                             n_valid=n_valid)
 
+    @property
+    def decodes_in_pool(self) -> bool:
+        """Whether :meth:`decode_paged` applies: every cache of the stack is
+        a full-length GQA cache."""
+        return tf.paged_decode_applies(self.cfg)
+
+    def decode_paged(self, params, tokens, pool, bt, pos, n_valid=None):
+        """:meth:`decode` against a page pool of full-length GQA caches
+        (``pool`` as ``PagedKVCacheManager`` holds it, ``bt`` the block
+        tables): K/V stay in their pages, each layer writes only the new
+        entries.  Returns (logits, updated pool)."""
+        return tf.lm_decode(params, tokens, pool, pos, self.cfg, self.plan,
+                            n_valid=n_valid, bt=bt)
+
     # --- caches ---------------------------------------------------------------
     def cache(self, batch_size: int, max_len: int, abstract: bool = False):
         cfg, plan = self.cfg, self.plan

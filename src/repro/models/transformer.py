@@ -69,9 +69,15 @@ def attn_block_apply(p, x, cfg, plan, positions=None, collect_kv=False):
     return (x, aux, kv) if collect_kv else (x, aux)
 
 
-def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None):
+def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None, pages=None):
+    """One block's decode step; ``pages`` = (layer, block table) runs its
+    attention against the page pool ``cache``
+    (:func:`attn.gqa_decode_paged`)."""
     h = L.norm_apply(p["ln1"], x, cfg)
-    if cfg.attn_type == "mla":
+    if pages is not None:
+        a, cache = attn.gqa_decode_paged(p["attn"], h, cache, *pages, pos,
+                                         cfg, plan, n_valid=n_valid)
+    elif cfg.attn_type == "mla":
         a, cache = attn.mla_decode(p["attn"], h, cache, pos, cfg, plan,
                                    n_valid=n_valid)
     else:
@@ -406,14 +412,32 @@ def lm_prefill(params, tokens, cfg: ModelConfig, plan: Plan,
 # decode step
 # =============================================================================
 
+def paged_decode_applies(cfg: ModelConfig) -> bool:
+    """True when every cache of the stack is a full-length GQA cache (one
+    layer stack of ``k``/``v``/``pos_ids``: no MLA, sliding window or dense
+    prefix blocks), so :func:`lm_decode` can run against a page pool."""
+    return (cfg.family in ("dense", "moe") and not cfg.first_k_dense
+            and cfg.attn_type != "mla" and not cfg.sliding_window)
+
+
 def lm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
-              n_valid=None):
+              n_valid=None, bt=None):
     """tokens:(B,S) -> logits:(B,S,V); functional cache update.
 
     ``pos`` may be a scalar or a (B,) vector of per-slot positions, and S may
     exceed 1 (chunked-prefill extend, attention families); ``n_valid`` (B,)
     marks real tokens per row for ragged extends.
+
+    With block tables ``bt`` (B, W), ``cache`` is a page pool
+    ``{"stack": {"k", "v", "pos_ids"}}`` with pages on axis 1 (only where
+    :func:`paged_decode_applies`): each layer writes its new entries into
+    their pages and reads its own pages through ``bt``.  The pool is carried
+    through the layer scan, not returned as its ys (which would stack a
+    copy), and comes back updated.
     """
+    if bt is not None and not paged_decode_applies(cfg):
+        raise ValueError("paged decode needs a stack of full-length GQA "
+                         "caches (no MLA, sliding window or dense prefix)")
     x = L.embed_apply(params["embed"], tokens, cfg, plan)
 
     if cfg.family in ("dense", "moe"):
@@ -422,14 +446,28 @@ def lm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
                 params["blocks"][f"dense{i}"], x, cache[f"dense{i}"], pos, cfg,
                 plan, n_valid=n_valid)
 
-        def body(x, pc):
-            lp, lc = pc
-            x, lc = attn_block_decode(lp, x, lc, pos, cfg, plan,
-                                      n_valid=n_valid)
-            return x, lc
+        stack = params["blocks"]["stack"]
+        if bt is None:
+            def body(x, pc):
+                lp, lc = pc
+                x, lc = attn_block_decode(lp, x, lc, pos, cfg, plan,
+                                          n_valid=n_valid)
+                return x, lc
 
-        x, new_stack = jax.lax.scan(
-            body, x, (params["blocks"]["stack"], cache["stack"]))
+            x, new_stack = jax.lax.scan(body, x, (stack, cache["stack"]))
+        else:
+            def body(carry, pl):
+                x, pool = carry
+                lp, layer = pl
+                x, pool = attn_block_decode(lp, x, pool, pos, cfg, plan,
+                                            n_valid=n_valid,
+                                            pages=(layer, bt))
+                return (x, pool), None
+
+            n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+            (x, new_stack), _ = jax.lax.scan(
+                body, (x, cache["stack"]),
+                (stack, jnp.arange(n, dtype=jnp.int32)))
         cache = {**cache, "stack": new_stack}
     elif cfg.family == "ssm":
         def body(x, pc):
@@ -470,3 +508,4 @@ def lm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
     x = L.norm_apply(params["final_ln"], x, cfg)
     logits = L.unembed_apply(params["embed"], x, cfg, plan)
     return logits, cache
+

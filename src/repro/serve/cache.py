@@ -447,10 +447,12 @@ class PagedKVCacheManager:
     ``gather_logical``/``scatter_logical`` convert between the pool and the
     slot-contiguous logical layout ``Model.decode`` expects; they are plain
     traceable functions so the engine can fuse gather -> decode -> scatter
-    into one jitted step.  Because the gathered logical cache is bitwise
-    equal to the contiguous manager's cache at every mask-visible entry
-    (and ``pos_ids`` equal everywhere — freed pages are invalidated), the
-    paged engine's logits are bitwise identical to the contiguous path.
+    into one jitted step for layouts that ``Model.decode_paged`` (which
+    works on the pool itself) does not take.  Because the gathered logical
+    cache is bitwise equal to the contiguous manager's cache at every
+    mask-visible entry (and ``pos_ids`` equal everywhere — freed pages are
+    invalidated), the paged engine's logits are bitwise identical to the
+    contiguous path.
     """
 
     def __init__(self, model, slots: int, max_len: int,
@@ -533,7 +535,9 @@ class PagedKVCacheManager:
             return jax.tree_util.tree_map_with_path(
                 inv, self.batch_axes, pool)
 
-        self._invalidate_pages = jax.jit(_invalidate_pages)
+        # donated: only pos_ids rows change, so the K/V leaves stay in
+        # place instead of a whole-pool copy on every release
+        self._invalidate_pages = jax.jit(_invalidate_pages, donate_argnums=0)
         self._gather = jax.jit(self.gather_logical)
         self._scatter = jax.jit(self.scatter_logical)
 
@@ -570,11 +574,16 @@ class PagedKVCacheManager:
         return inv
 
     def scatter_all(self, pool, logical, inv):
-        """Write the full-batch logical cache back into the pool through
-        the :meth:`inverse_map` — one gather per leaf (no scatter op on
-        the hot path).  Unallocated pages and the null page come out as
-        the fill (``pos_ids = -1``, zeros elsewhere), so stale entries and
-        the aliased null writes stay inert by construction."""
+        """Write the fused step's cache back into the pool: the full-batch
+        logical cache through the :meth:`inverse_map` — one gather per leaf
+        (no scatter op on the hot path).  Unallocated pages and the null
+        page come out as the fill (``pos_ids = -1``, zeros elsewhere), so
+        stale entries and the aliased null writes stay inert by
+        construction.  With ``inv`` None the step ran in the pool
+        (``Model.decode_paged``), ``logical`` is the stepped pool itself,
+        and it is the result."""
+        if inv is None:
+            return logical
         ps = self.page_size
 
         def put(path, ba, sa, pc, lg):

@@ -14,12 +14,17 @@ Cache state lives in :class:`~repro.serve.cache.KVCacheManager` (or, with
 ``paged=True``, :class:`~repro.serve.cache.PagedKVCacheManager` — free-list
 pages behind per-slot block tables): per-slot positions, page accounting,
 slot recycling (freed rows/pages are invalidated via ``pos_ids = -1`` and
-reused without growing the arrays).  On the paged path the fused step
-gathers a slot-contiguous logical cache through the block tables, runs the
-unchanged ``Model.decode``, and scatters pages back — one jit, outputs
-bitwise identical to the contiguous manager — and admission/extension run
-at page granularity off the actual free list, so churn that would fragment
-contiguous rows costs nothing.
+reused without growing the arrays).  On the paged path, when every cache
+of the stack is a full-length GQA cache (``Model.decodes_in_pool``), K/V
+never leaves the page pool: the fused step hands the donated pool and the
+block tables to ``Model.decode_paged``, whose layers each write the tick's
+new entries into their pages and read their own pages through the tables.
+Other layouts (ring caches of sliding-window models, MLA, dense prefix
+blocks) gather a slot-contiguous logical cache through the block tables,
+run ``Model.decode`` and scatter pages back.  Either way it is one jit with outputs bitwise identical to the
+contiguous manager, and admission/extension run at page granularity off
+the actual free list, so churn that would fragment contiguous rows costs
+nothing.
 
 ``speculate=k`` adds draft-k self-speculative decode (greedy only):
 n-gram prompt-lookup drafts ride the same ragged ``pos``/``n_valid``
@@ -51,11 +56,13 @@ so they land on the same clock as the device's operations:
 ``serve.engine.admit``, ``.compose`` (plan, page reservation, preemption),
 ``.upload`` (key split, tick arrays, block table; stat ``bt_sent``),
 ``.dispatch`` (the fused call; stats ``width``, ``prefill``, ``decode``,
-``prompt_tokens``), ``.sync`` (the host copy of the output), ``.commit``
-(advance and append) and ``.release`` (pages returned, stat ``pages``;
-nested in ``commit``, or in ``compose`` when preemption frees a slot).
-Inside the fused step, ``jax.named_scope`` marks ``kv_gather``,
-``decode``, ``sample`` and ``kv_scatter`` in every device op's metadata.
+``prompt_tokens``, ``kv_pool``: 1 on the in-pool path), ``.sync`` (the
+host copy of the output), ``.commit`` (advance and append) and
+``.release`` (pages returned, stat ``pages``; nested in ``commit``, or in
+``compose`` when preemption frees a slot).  Inside the fused step,
+``jax.named_scope`` marks ``decode`` (with ``kv_write`` and ``kv_read`` in
+each layer on the in-pool path), ``sample``, and on the gather path
+``kv_gather`` and ``kv_scatter``, in every device op's metadata.
 Each :class:`Request` carries ``perf_counter`` stamps of its submission,
 first admission, first token and completion.
 """
@@ -192,7 +199,29 @@ class Engine:
             with jax.named_scope("sample"):
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        if self._paged:
+        self._kv_pool = self._paged and model.decodes_in_pool
+        self.kv_pool_ticks = 0  # fused ticks run on the in-pool path
+        if self._kv_pool:
+            mgr = self.mgr
+
+            def decode_paged(params, tokens, pool, bt, pos, n_valid):
+                with jax.named_scope("decode"):
+                    logits, out = model.decode_paged(
+                        params, tokens, pool, bt, pos, n_valid=n_valid)
+                # the layers wrote their entries in place; the manager's
+                # write-back hands the stepped pool on as it is
+                return logits, mgr.scatter_all(pool, out, None)
+
+            def fused(params, pool, bt, tokens, pos, n_valid, key):
+                logits, pool = decode_paged(params, tokens, pool, bt, pos,
+                                            n_valid)
+                return pick(logits, n_valid, key), pool
+
+            def fused_spec(params, pool, bt, tokens, pos, n_valid, key):
+                logits, pool = decode_paged(params, tokens, pool, bt, pos,
+                                            n_valid)
+                return verify_rows(logits), pool
+        elif self._paged:
             mgr = self.mgr
 
             def gather(pool, bt):
@@ -221,10 +250,8 @@ class Engine:
                 logits, cache = decode(params, tokens, cache, pos, n_valid)
                 return verify_rows(logits), cache
 
-        # the paged step donates the pool: the scatter then updates the
-        # page buffers in place instead of copying the whole pool per
-        # layer (the block-table indirection's write path is what keeps
-        # the paged tick within the decode-latency tax budget)
+        # the paged step donates the pool: its writes then update the page
+        # buffers in place instead of copying the whole pool
         donate = (1,) if self._paged else ()
         self._fused = jax.jit(fused, donate_argnums=donate)
         self._fused_spec = jax.jit(fused_spec, donate_argnums=donate)
@@ -232,9 +259,9 @@ class Engine:
             self._warmup()
 
     def _run_fused(self, fn, plan: sched.TickPlan) -> np.ndarray:
-        """One fused device step over the plan (gather -> decode -> scatter
-        on the paged path) under the upload, dispatch and sync spans;
-        returns the host copy of the sampled output."""
+        """One fused device step over the plan (on the paged path: in the
+        pool, or gather -> decode -> scatter) under the upload, dispatch
+        and sync spans; returns the host copy of the sampled output."""
         with span("serve.engine.upload",
                   bt_sent=int(self._paged and self._bt_stale())):
             self.key, key = jax.random.split(self.key)
@@ -242,14 +269,16 @@ class Engine:
             pos = jnp.asarray(plan.pos)
             nv = jnp.asarray(plan.n_valid)
             if self._paged:
-                bt, inv = self._bt_device()
+                tables = self._bt_device()
         prefill = [w for w in plan.work if w.kind == "prefill"]
         with span("serve.engine.dispatch", width=plan.width,
                   prefill=len(prefill), decode=len(plan.work) - len(prefill),
-                  prompt_tokens=sum(len(w.tokens) for w in prefill)):
+                  prompt_tokens=sum(len(w.tokens) for w in prefill),
+                  kv_pool=int(self._kv_pool)):
             if self._paged:
-                out, self.mgr.pool = fn(self.params, self.mgr.pool, bt, inv,
+                out, self.mgr.pool = fn(self.params, self.mgr.pool, *tables,
                                         toks, pos, nv, key)
+                self.kv_pool_ticks += self._kv_pool
             else:
                 out, self.mgr.cache = fn(self.params, self.mgr.cache,
                                          toks, pos, nv, key)
@@ -261,15 +290,18 @@ class Engine:
         return self._bt_host is None or not np.array_equal(
             self._bt_host, self.mgr.block_table)
 
-    def _bt_device(self):
-        """Device copies of the block table and its inverse page map,
+    def _bt_device(self) -> tuple:
+        """The fused step's page-table arguments on the device: the block
+        table, and on the gather/scatter path its inverse page map too;
         re-uploaded only when the host table actually changed (steady
         decode re-uses pages for page_size ticks at a time, so most ticks
         skip the transfer)."""
         if self._bt_stale():
             self._bt_host = self.mgr.block_table.copy()
-            self._bt_dev = (jnp.asarray(self._bt_host, jnp.int32),
-                            jnp.asarray(self.mgr.inverse_map(), jnp.int32))
+            self._bt_dev = (jnp.asarray(self._bt_host, jnp.int32),)
+            if not self._kv_pool:
+                self._bt_dev += (jnp.asarray(self.mgr.inverse_map(),
+                                             jnp.int32),)
         return self._bt_dev
 
     def _warmup(self):
@@ -286,13 +318,13 @@ class Engine:
             if self._paged:
                 # the pool is donated into the jit — rebind the returned
                 # buffer or the manager would hold a deleted array
-                bt, inv = self._bt_device()
-                _, self.mgr.pool = fn(self.params, self.mgr.pool, bt, inv,
-                                      toks, zero, zero, self.key)
+                _, self.mgr.pool = fn(self.params, self.mgr.pool,
+                                      *self._bt_device(), toks, zero, zero,
+                                      self.key)
             else:
                 fn(self.params, self.mgr.cache, toks, zero, zero, self.key)
         if self._paged:
-            self.mgr._invalidate_pages(
+            self.mgr.pool = self.mgr._invalidate_pages(
                 self.mgr.pool, jnp.asarray([self.mgr.null_page]))
         else:
             self.mgr._invalidate(self.mgr.cache, jnp.asarray([0]))

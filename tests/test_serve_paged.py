@@ -5,7 +5,11 @@ never a numerics change — paged replay is bitwise the contiguous replay
 (dense + SWA), the speculative accepted prefix is bitwise the greedy
 sequence, and the free-list allocator admits strictly more concurrent
 work than contiguous slots at the same page budget (the churn workload).
+Full-length GQA stacks keep K/V in the page pool (``TestInPoolStep``);
+ring and latent caches keep the gather/scatter step.
 """
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -151,6 +155,33 @@ class TestPagedManagerLifecycle:
             mgr.free(s)
         with pytest.raises(ValueError, match="invalid slot"):
             mgr.free(2)
+
+    @pytest.mark.parametrize("release", ["free", "trim"])
+    def test_release_invalidates_in_place(self, dense, release):
+        """A release writes only the freed pages' ``pos_ids``: the pool is
+        donated, so the K/V leaves keep their buffers (no whole-pool copy)
+        and the other slot's entries are untouched."""
+        _, model, _ = dense
+        mgr = PagedKVCacheManager(model, slots=2, max_len=64, page_size=16)
+        a, b = mgr.allocate(40), mgr.allocate(20)
+        mgr.extend(a, 40)
+        mgr.extend(b, 20)
+        mgr.pool = jax.tree_util.tree_map(lambda x: x + 1, mgr.pool)
+        kv = {n: mgr.pool["stack"][n] for n in ("k", "v")}
+        at = {n: x.unsafe_buffer_pointer() for n, x in kv.items()}
+        pages = mgr.block_table[a, :3].copy()
+        if release == "free":
+            mgr.free(a)
+        else:
+            assert mgr.trim(a, 16) == 2
+            pages = pages[1:]
+        assert all(x.is_deleted() for x in kv.values())
+        assert {n: mgr.pool["stack"][n].unsafe_buffer_pointer()
+                for n in kv} == at
+        ids = np.asarray(mgr.pool["stack"]["pos_ids"])
+        assert (ids[:, pages] == -1).all()
+        kept = [int(p) for p in mgr.block_table[b] if p != mgr.null_page]
+        assert (ids[:, kept] == 0).all()  # 0 = -1 + 1: not invalidated
 
     def test_inverse_map_inverts_the_block_table(self, dense):
         _, model, _ = dense
@@ -337,3 +368,148 @@ class TestPagedPreemption:
         eng.run()
         assert {r.rid: tuple(r.out) for r in eng.finished} == ref
         assert eng.pool.pages_held == 0 and eng.preempts == 1
+
+
+@pytest.fixture(scope="module")
+def mla():
+    cfg = registry.get("deepseek-v2-236b").reduced()
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(2))
+    return cfg, model, params
+
+
+# (tick, rid, prompt length, max_new): three slots of three 8-token pages
+# and chunks of 8; rids 2-4 arrive mid-flight, 3 and 4 into released slots
+WAVES = [(0, 0, 12, 9), (0, 1, 5, 14), (2, 2, 11, 6), (5, 3, 3, 10),
+         (6, 4, 9, 5)]
+
+
+def _serve_waves(model, params, cfg, on_fused=None, **kw):
+    """Serve WAVES through an engine; ``on_fused(eng, run, fn, plan)``
+    wraps every fused tick.  Returns the engine and the tokens by rid."""
+    eng = Engine(model, params, batch_slots=3, max_len=64, page_size=8,
+                 prefill_chunk=8, eos_id=-1, warmup=False, **kw)
+    if on_fused is not None:
+        run = eng._run_fused
+        eng._run_fused = lambda fn, plan: on_fused(eng, run, fn, plan)
+    due = list(WAVES)
+    tick = 0
+    while due or eng.step():
+        while due and due[0][0] <= tick:
+            _, rid, n, m = due.pop(0)
+            eng.submit(Request(rid, _prompt(cfg, rid, n), max_new=m))
+        if due:
+            eng.step()
+        tick += 1
+    return eng, {r.rid: tuple(r.out) for r in eng.finished}
+
+
+class TestInPoolStep:
+    """Full-length GQA stacks keep K/V in the page pool: each layer writes
+    only the tick's new entries into their pages and reads its own pages
+    through the block table (``Model.decode_paged``)."""
+
+    @pytest.mark.parametrize("speculate", [0, 3])
+    def test_mixed_ticks_bitwise_contiguous(self, dense, speculate):
+        cfg, model, params = dense
+        _, ref = _serve_waves(model, params, cfg)
+        widths = []
+
+        def spy(eng, run, fn, plan):
+            widths.append((plan.width, tuple(plan.n_valid)))
+            return run(fn, plan)
+
+        eng, got = _serve_waves(model, params, cfg, on_fused=spy, paged=True,
+                                speculate=speculate)
+        assert got == ref, "the in-pool step changed the tokens"
+        assert eng.kv_pool_ticks == len(widths) > 0
+        chunk = [nv for w, nv in widths if w == 8]
+        assert any(0 in nv and 1 in nv and any(1 < n < 8 for n in nv)
+                   for nv in chunk), chunk  # empty, decode, partial prompt
+        if speculate:
+            assert eng.spec_accepted > 0
+        assert eng.mgr.pages_in_use == eng.mgr.recount_pages() == 0
+
+    def test_pool_after_each_tick_matches_gather_decode_scatter(self, dense):
+        """Every tick's pool equals what the gather -> ``Model.decode`` ->
+        ``scatter_all`` step leaves from the same pool: ``pos_ids``
+        everywhere, K/V at every entry with ``pos_ids >= 0``; the null page
+        stays invalid."""
+        import jax.numpy as jnp
+        cfg, model, params = dense
+        checked = []
+
+        @functools.partial(jax.jit, static_argnums=0)
+        def old_step(mgr, pool, bt, inv, tokens, pos, n_valid):
+            _, logical = model.decode(params, tokens,
+                                      mgr.gather_logical(pool, bt), pos,
+                                      n_valid=n_valid)
+            return mgr.scatter_all(pool, logical, inv)
+
+        def check(eng, run, fn, plan):
+            mgr = eng.mgr
+            before = jax.tree_util.tree_map(jnp.copy, mgr.pool)
+            bt = jnp.asarray(mgr.block_table, jnp.int32)
+            inv = jnp.asarray(mgr.inverse_map(), jnp.int32)
+            out = run(fn, plan)
+            ref = old_step(mgr, before, bt, inv, jnp.asarray(plan.tokens),
+                           jnp.asarray(plan.pos),
+                           jnp.asarray(plan.n_valid))["stack"]
+            got = mgr.pool["stack"]
+            ids = np.asarray(got["pos_ids"])
+            np.testing.assert_array_equal(ids, np.asarray(ref["pos_ids"]))
+            live = ids >= 0
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(got[name])[live], np.asarray(ref[name])[live])
+            assert (ids[:, mgr.null_page] == -1).all()
+            checked.append(plan.width)
+            return out
+
+        _serve_waves(model, params, cfg, on_fused=check, paged=True)
+        assert 1 in checked and 8 in checked
+
+    def test_write_near_max_len_keeps_live_entries(self, dense):
+        """PERF.md §7's witness (2 layers x 64, ``max_len`` 64, chunk 16):
+        a slot decoding at position 54 keeps its 54 cached entries through
+        another slot's prompt tick and adds the 55th.  The parent's paged
+        step held 49: its logical write (``attention._row_update``) clamps
+        an S-wide row to start at ``T - S``, over live entries.  The
+        contiguous manager still writes through ``_row_update`` and still
+        clamps there."""
+        cfg, model, params = dense
+        eng = Engine(model, params, batch_slots=2, max_len=64,
+                     prefill_chunk=16, page_size=16, paged=True, eos_id=-1,
+                     warmup=False)
+        a = Request(0, _prompt(cfg, 0, 40), max_new=20)
+        eng.submit(a)
+        slot = None
+        while slot is None or eng.mgr.pos[slot] < 54:
+            eng.step()
+            slot = eng.slot_req.index(a)
+        assert eng.mgr.pos[slot] == 54
+        b = Request(1, _prompt(cfg, 1, 20), max_new=4)
+        eng.submit(b)
+        eng.step()
+        assert b.fed == 16  # the tick was chunk-wide
+        ids = np.asarray(eng.mgr.read_rows([slot])["stack"]["pos_ids"])
+        for layer in ids[:, 0]:
+            assert sorted(layer[layer >= 0]) == list(range(55))
+
+    @pytest.mark.parametrize("layout", ["swa", "mla"])
+    def test_ring_and_latent_caches_keep_the_gather_path(self, request,
+                                                         layout):
+        """Sliding-window (ring) and MLA stacks do not hold full-length GQA
+        pages: they keep gather -> decode -> scatter, bitwise the
+        contiguous engine, and the in-pool entry point refuses them."""
+        import jax.numpy as jnp
+        cfg, model, params = request.getfixturevalue(layout)
+        _, ref = _outs(cfg, model, params, n_req=3, max_new=6)
+        eng, paged = _outs(cfg, model, params, n_req=3, max_new=6,
+                           paged=True)
+        assert paged == ref
+        assert eng.kv_pool_ticks == 0 and eng.ticks > 0
+        bt = jnp.asarray(eng.mgr.block_table, jnp.int32)
+        with pytest.raises(ValueError, match="full-length GQA"):
+            model.decode_paged(params, jnp.zeros((2, 1), jnp.int32),
+                               eng.mgr.pool, bt, jnp.zeros(2, jnp.int32))

@@ -31,6 +31,13 @@ def dense():
     return cfg, model, model.init(jax.random.PRNGKey(0))
 
 
+@pytest.fixture(scope="module")
+def swa():
+    cfg = registry.get("mixtral-8x7b").reduced()
+    model = Model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(1))
+
+
 def _engine(dense, **kw):
     _, model, params = dense
     kw = {"batch_slots": 3, "max_len": 64, "prefill_chunk": CHUNK,
@@ -97,6 +104,7 @@ def test_every_tick_holds_the_phases_in_order(dense, tmp_path):
         assert d["width"] == (CHUNK if d["prefill"] else 1)
         assert d["prompt_tokens"] <= CHUNK * d["prefill"]
         assert (d["prompt_tokens"] > 0) == (d["prefill"] > 0)
+        assert d["kv_pool"] == 1  # dense GQA: K/V stays in the page pool
         fed += d["prompt_tokens"]
         sent.append(top[PHASES.index("upload")].stats["bt_sent"])
     # every request releases its pages once, inside the commit that ends it
@@ -145,27 +153,45 @@ def test_request_stamps_are_ordered_through_preemption(dense):
 
 
 @pytest.mark.parametrize("paged, spec, scopes", [
-    (True, False, ("kv_gather", "decode", "sample", "kv_scatter")),
-    (True, True, ("kv_gather", "decode", "sample", "kv_scatter")),
+    (True, False, ("decode", "kv_write", "kv_read", "sample")),
+    (True, True, ("decode", "kv_write", "kv_read", "sample")),
     (False, False, ("decode", "sample")),
 ])
 def test_fused_step_op_metadata_names_its_layers(dense, paged, spec, scopes):
-    eng = _engine(dense, paged=paged, speculate=2 if spec else 0)
+    names = _fused_op_names(_engine(dense, paged=paged,
+                                    speculate=2 if spec else 0), spec)
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in names), scope
+    # K/V stays in the pool (dense GQA): no whole-cache gather or scatter
+    for scope in ("kv_gather", "kv_scatter"):
+        assert not any(f"/{scope}/" in n for n in names), scope
+
+
+@pytest.mark.parametrize("spec", [False, True])
+def test_gather_path_op_metadata_names_its_layers(swa, spec):
+    """A sliding-window stack keeps gather -> decode -> scatter, and the
+    step's op metadata names those layers."""
+    eng = _engine(swa, speculate=2 if spec else 0)
+    assert not eng._kv_pool
+    names = _fused_op_names(eng, spec)
+    for scope in ("kv_gather", "decode", "sample", "kv_scatter"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    for scope in ("kv_write", "kv_read"):
+        assert not any(f"/{scope}/" in n for n in names), scope
+
+
+def _fused_op_names(eng, spec):
+    """The op names in the compiled fused step's metadata."""
     fn = eng._fused_spec if spec else eng._fused
     B, S_ = eng.B, 3 if spec else 1
     args = (jax.numpy.zeros((B, S_), jax.numpy.int32),
             jax.numpy.zeros((B,), jax.numpy.int32),
             jax.numpy.zeros((B,), jax.numpy.int32), eng.key)
-    if paged:
-        bt, inv = eng._bt_device()
-        lowered = fn.lower(eng.params, eng.mgr.pool, bt, inv, *args)
+    if eng._paged:
+        lowered = fn.lower(eng.params, eng.mgr.pool, *eng._bt_device(), *args)
     else:
         lowered = fn.lower(eng.params, eng.mgr.cache, *args)
-    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
-    for scope in scopes:
-        assert any(f"/{scope}/" in n for n in names), scope
-    if not paged:
-        assert not any("/kv_gather/" in n for n in names)
+    return re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
 
 
 def test_small_cell_reads_the_engine_stamps(tmp_path, monkeypatch):

@@ -77,9 +77,12 @@ def test_abft_matmul_qwen3_mlp(one_chip):
 
 
 def test_paged_fused_decode_step_qwen3(one_chip):
-    """The engine's paged fused step (gather -> decode -> scatter) at
-    qwen3-1.7b width, cut to 2 layers; 8 slots of 1024 tokens."""
-    model = Model(registry.get("qwen3-1.7b").replace(num_layers=2))
+    """The engine's paged fused step (K/V kept in the page pool) at
+    qwen3-1.7b width, cut to 2 layers, with bf16 weights as served (float32
+    weights would add their casts to the temporaries); 8 slots of 1024
+    tokens."""
+    model = Model(registry.get("qwen3-1.7b").replace(
+        num_layers=2, param_dtype="bfloat16"))
     eng = Engine(model, None, batch_slots=8, max_len=1024,
                  prefill_chunk=256, paged=True, warmup=False)
 
@@ -87,14 +90,17 @@ def test_paged_fused_decode_step_qwen3(one_chip):
         return jax.tree_util.tree_map(
             lambda x: _spec(x.shape, x.dtype, one_chip), tree)
 
-    bt, inv = eng._bt_device()
     slots = _spec((8,), jnp.int32, one_chip)
     compiled = eng._fused.lower(
         on_chip(model.abstract_params()), on_chip(eng.mgr.pool),
-        on_chip(bt), on_chip(inv), _spec((8, 1), jnp.int32, one_chip),
+        *on_chip(eng._bt_device()), _spec((8, 1), jnp.int32, one_chip),
         slots, slots, on_chip(eng.key)).compile()
-    # the pool is donated: the scatter writes it in place (the device
-    # layout pads the small pos_ids leaves, so aliased bytes >= nbytes)
-    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+    m = compiled.memory_analysis()
+    # the pool is donated: the writes land in place (the device layout
+    # pads the small pos_ids leaves, so aliased bytes >= nbytes)
+    assert m.alias_size_in_bytes >= sum(
         x.nbytes for x in jax.tree_util.tree_leaves(eng.mgr.pool))
+    # no buffer the size of the stack's logical K/V (2 layers x 8 slots x
+    # 1024 positions x 4,096 B), which the gather/scatter step materialised
+    assert m.temp_size_in_bytes < 2 * 8 * 1024 * 4096, m.temp_size_in_bytes
     _fits(compiled)
