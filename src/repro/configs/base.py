@@ -54,6 +54,9 @@ class ModelConfig:
     norm_eps: float = 1e-5
     qk_norm: bool = False
     rope_theta: float = 500_000.0
+    # the source's ``rope_scaling`` mapping (only ``{"type": "yarn", ...}``
+    # is read); held as sorted (key, value) pairs so the config hashes
+    rope_scaling: Optional[Tuple[Tuple[str, object], ...]] = None
     use_rope: bool = True
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
@@ -75,6 +78,17 @@ class ModelConfig:
     moe_d_ff: int = 0  # per-expert hidden
     first_k_dense: int = 0  # leading dense layers in an MoE stack
     moe_capacity_factor: float = 1.25
+    # gate (DeepSeek-V2 ``group_limited_greedy``): experts fall in n_group
+    # groups, a token keeps the topk_group groups of highest max score and
+    # picks its top-k inside them; 0 = plain top-k over all experts
+    n_group: int = 0
+    topk_group: int = 0
+    norm_topk_prob: bool = True  # renormalise the k weights, else scale them
+    routed_scaling_factor: float = 1.0
+    # experts this chip holds (expert parallelism): held_experts of the
+    # num_experts from held_expert_start; 0 = all of them
+    held_expert_start: int = 0
+    held_experts: int = 0
     router_aux_coef: float = 0.001
     router_z_coef: float = 0.001
 
@@ -107,10 +121,29 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        if self.held_expert_start + self.n_held > self.num_experts:
+            raise ValueError(
+                f"experts {self.held_expert_start}.."
+                f"{self.held_expert_start + self.n_held - 1} are not among "
+                f"the {self.num_experts}")
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def n_held(self) -> int:
+        """How many experts this chip holds (all of them unless cut)."""
+        return self.held_experts or self.num_experts
+
+    @property
+    def yarn(self) -> Optional[dict]:
+        """The YaRN rope scaling settings, or None."""
+        rs = dict(self.rope_scaling or ())
+        return rs if rs.get("type") == "yarn" else None
 
     @property
     def is_ssm(self) -> bool:
@@ -151,7 +184,10 @@ class ModelConfig:
                       num_experts_per_tok=min(self.num_experts_per_tok, 2),
                       moe_d_ff=64,
                       num_shared_experts=self.num_shared_experts and 1,
-                      first_k_dense=min(self.first_k_dense, 1))
+                      first_k_dense=min(self.first_k_dense, 1),
+                      held_expert_start=0, held_experts=0)
+            if self.n_group:
+                kw.update(n_group=4, topk_group=2)
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
         if self.hybrid_attn_every:

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamMeta, dense
-from repro.models.layers import apply_rope, rms_norm
+from repro.models.layers import apply_rope, rms_norm, yarn_attn_mscale
 from repro.sharding.plan import Plan
 
 NEG_INF = -1e30
@@ -113,8 +113,9 @@ BLOCKWISE_THRESHOLD = 8192  # self-attention seqs >= this use blockwise softmax
 # saving; blockwise pays off from 8k where scores no longer fit)
 
 
-def _sdpa(q, k, v, mask, plan: Plan):
-    """q:(B,S,H,D) k,v:(B,T,Hkv,D) mask:(B,1,1,S,T) or None -> (B,S,H,D)."""
+def _sdpa(q, k, v, mask, plan: Plan, scale=None):
+    """q:(B,S,H,D) k,v:(B,T,Hkv,D) mask:(B,1,1,S,T) or None -> (B,S,H,D);
+    scores are scaled by ``scale``, by default ``D ** -0.5``."""
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     G = H // Hkv
@@ -123,7 +124,10 @@ def _sdpa(q, k, v, mask, plan: Plan):
     # f32 copies of K (and force an f32 cache carry through the decode scan)
     scores = jnp.einsum("bshgd,bthd->bhgst", q, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scores = scores / jnp.sqrt(D).astype(jnp.float32)
+    else:
+        scores = scores * scale
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
@@ -132,7 +136,7 @@ def _sdpa(q, k, v, mask, plan: Plan):
 
 
 def blockwise_sdpa(q, k, v, *, causal: bool, window: int = 0,
-                   q_block: int = 2048, kv_block: int = 2048):
+                   q_block: int = 2048, kv_block: int = 2048, scale=None):
     """Flash-style online-softmax attention in pure XLA (scan over blocks).
 
     Never materializes the (S,T) score matrix — per-step live memory is
@@ -147,7 +151,8 @@ def blockwise_sdpa(q, k, v, *, causal: bool, window: int = 0,
     q_block = min(q_block, S)
     kv_block = min(kv_block, T)
     nq, nk = S // q_block, T // kv_block
-    scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)
 
     qb = q.reshape(B, nq, q_block, Hkv, G, D)
     kb = k.reshape(B, nk, kv_block, Hkv, D)
@@ -328,8 +333,25 @@ def gqa_decode_paged(p, x, pool, layer, bt, pos, cfg: ModelConfig,
     positions = decode_positions(pos, B, S)  # (B,S)
     q = apply_rope(q, positions, cfg)
     k_new = apply_rope(k_new, positions, cfg)
-    ids = _new_pos_ids(positions, n_valid)
-    n_pages, page = pool["pos_ids"].shape[1:]
+    pool = _paged_write(pool, layer, bt, positions,
+                        _new_pos_ids(positions, n_valid),
+                        {"k": k_new, "v": v_new})
+    view = _paged_read(pool, layer, bt)
+    o = _attend_full(q, view["k"], view["v"], view["pos_ids"], positions,
+                     plan)
+    o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(x.dtype))
+    return o, pool
+
+
+def _paged_write(pool, layer, bt, positions, ids, new):
+    """Write a chunk's new entries ``new`` ({leaf: (B,S,...)}) and their
+    position ids ``ids`` (B,S) into the page pool: position p of row b lands
+    on page ``bt[b, p // page]`` at offset ``p % page``.  Entries with
+    ``ids < 0`` (past ``n_valid``) and positions past the block table are
+    dropped, never written.  ``layer`` indexes the pool's leading layer
+    axis, or is None for a pool leaf of one layer."""
+    lead = () if layer is None else (layer,)
+    n_pages, page = pool["pos_ids"].shape[len(lead):]
     W = bt.shape[1]
     with jax.named_scope("kv_write"):
         j = positions // page
@@ -337,21 +359,24 @@ def gqa_decode_paged(p, x, pool, layer, bt, pos, cfg: ModelConfig,
         dest = jnp.where(
             keep, jnp.take_along_axis(bt, jnp.minimum(j, W - 1), axis=1),
             n_pages)  # out of range: the scatter drops it
-        at = (layer, dest, positions % page)
-        pool = {
-            "k": pool["k"].at[at].set(k_new.astype(pool["k"].dtype),
-                                      mode="drop"),
-            "v": pool["v"].at[at].set(v_new.astype(pool["v"].dtype),
-                                      mode="drop"),
-            "pos_ids": pool["pos_ids"].at[at].set(ids, mode="drop"),
-        }
+        at = lead + (dest, positions % page)
+        out = {name: pool[name].at[at].set(v.astype(pool[name].dtype),
+                                           mode="drop")
+               for name, v in new.items()}
+        out["pos_ids"] = pool["pos_ids"].at[at].set(ids, mode="drop")
+    return out
+
+
+def _paged_read(pool, layer, bt):
+    """Each row's pages of one layer through the block table, as a
+    (B, W*page, ...) logical view of every leaf."""
+    lead = () if layer is None else (layer,)
+    B, W = bt.shape
+    page = pool["pos_ids"].shape[-1]
     with jax.named_scope("kv_read"):
-        k = pool["k"][layer, bt].reshape((B, W * page) + pool["k"].shape[3:])
-        v = pool["v"][layer, bt].reshape((B, W * page) + pool["v"].shape[3:])
-        pos_ids = pool["pos_ids"][layer, bt].reshape(B, W * page)
-    o = _attend_full(q, k, v, pos_ids, positions, plan)
-    o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(x.dtype))
-    return o, pool
+        return {name: leaf[lead + (bt,)].reshape(
+                    (B, W * page) + leaf.shape[len(lead) + 2:])
+                for name, leaf in pool.items()}
 
 
 def gqa_seed_cache(cache, kv, prefill_len: int, lengths=None):
@@ -385,7 +410,7 @@ def gqa_seed_cache(cache, kv, prefill_len: int, lengths=None):
 
 
 # =============================================================================
-# MLA (deepseek-v2): low-rank compressed KV, absorbed decode
+# MLA (deepseek-v2): low-rank compressed KV, absorbed decode, paged latent
 # =============================================================================
 
 def mla_params(cfg: ModelConfig, plan: Plan):
@@ -425,45 +450,65 @@ def _mla_q(p, x, cfg, positions):
     return q_nope, q_rope
 
 
-def _mla_ckv(p, x, cfg, positions):
+def _mla_latent(p, x, cfg, positions):
+    """Each token's cache entry: its normalised compressed KV (``kv_lora_rank``)
+    followed by its roped shared key (``qk_rope_head_dim``), (B,T,r+rope)."""
     dt = x.dtype
     rope_d = cfg.qk_rope_head_dim
     kvd = x @ p["kv_down"].astype(dt)
     c_kv = rms_norm(kvd[..., : cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_rope = kvd[..., cfg.kv_lora_rank:][:, :, None, :]  # (B,T,1,rope)
     k_rope = apply_rope(k_rope, positions, cfg, dim=rope_d)[:, :, 0]
-    return c_kv, k_rope
+    return jnp.concatenate([c_kv, k_rope.astype(c_kv.dtype)], -1)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``q_head_dim ** -0.5``, times YaRN's ``m ** 2`` where rope is YaRN."""
+    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            * yarn_attn_mscale(cfg) ** 2)
+
+
+def _mla_expand(p, latent, cfg, dt):
+    """Per-head keys and values from cache entries (…,T,r+rope):
+    (…,T,H,nope+rope) and (…,T,H,v)."""
+    r = cfg.kv_lora_rank
+    c_kv, k_rope = latent[..., :r], latent[..., r:]
+    k_nope = jnp.einsum("...tr,rhk->...thk", c_kv, p["k_up"].astype(dt))
+    v = jnp.einsum("...tr,rhk->...thk", c_kv, p["v_up"].astype(dt))
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[..., None, :], k_nope.shape[:-1] + (k_rope.shape[-1],))], -1)
+    return k, v
 
 
 def mla_apply(p, x, cfg: ModelConfig, plan: Plan, positions=None):
-    """Train/prefill: expand compressed KV per head; returns (out, (c_kv,k_rope))."""
+    """Train/prefill: expand compressed KV per head; returns (out, latent)
+    with the cache entries (B,S,r+rope) for cache seeding."""
     B, S, _ = x.shape
     dt = x.dtype
-    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     if positions is None:
         positions = jnp.arange(S)[None]
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
-    c_kv, k_rope = _mla_ckv(p, x, cfg, positions)
-    k_nope = jnp.einsum("btr,rhk->bthk", c_kv, p["k_up"].astype(dt))
-    v = jnp.einsum("btr,rhk->bthk", c_kv, p["v_up"].astype(dt))
+    latent = _mla_latent(p, x, cfg, positions)
+    k, v = _mla_expand(p, latent, cfg, dt)
     q = jnp.concatenate([q_nope, q_rope], -1)
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(
-        k_rope[:, :, None, :], k_nope.shape[:3] + (rope_d,))], -1)
     q = plan.act(q, "batch", None, "heads", None)
     k = plan.act(k, "batch", None, "heads", None)
+    scale = mla_softmax_scale(cfg)
     if S >= BLOCKWISE_THRESHOLD:
-        o = blockwise_sdpa(q, k, v, causal=True)
+        o = blockwise_sdpa(q, k, v, causal=True, scale=scale)
     else:
-        o = _sdpa(q, k, v, causal_mask(S, S, 0), plan)
+        o = _sdpa(q, k, v, causal_mask(S, S, 0), plan, scale=scale)
     o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(dt))
-    return o, (c_kv, k_rope)
+    return o, latent
 
 
 def mla_cache_init(cfg, plan, batch, max_len, dtype, abstract=False):
+    """One leaf of cache entries, ``kv_lora_rank + qk_rope_head_dim`` values
+    per token (576 for DeepSeek-V2), and the position table."""
     mk = jax.ShapeDtypeStruct if abstract else (lambda s, d: jnp.zeros(s, d))
+    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
     return {
-        "c_kv": mk((batch, max_len, cfg.kv_lora_rank), dtype),
-        "k_rope": mk((batch, max_len, cfg.qk_rope_head_dim), dtype),
+        "latent": mk((batch, max_len, width), dtype),
         "pos_ids": (jax.ShapeDtypeStruct((batch, max_len), jnp.int32) if abstract
                     else jnp.full((batch, max_len), -1, jnp.int32)),
     }
@@ -472,54 +517,95 @@ def mla_cache_init(cfg, plan, batch, max_len, dtype, abstract=False):
 def mla_cache_spec(plan: Plan, seq_axis=None):
     from jax.sharding import PartitionSpec as P
     b = plan.batch_axes
-    return {"c_kv": P(b, seq_axis, None), "k_rope": P(b, seq_axis, None),
-            "pos_ids": P(b, seq_axis)}
+    return {"latent": P(b, seq_axis, None), "pos_ids": P(b, seq_axis)}
+
+
+def _mla_attend(p, q_nope, q_rope, latent, pos_ids, positions,
+                cfg: ModelConfig):
+    """Attention of queries (B,S,H,·) over cache entries (B,T,r+rope) under
+    the position-table mask, before the output projection: (B,S,H,v).
+
+    A decode step (S = 1) is absorbed: ``k_up`` is folded into the query,
+    which then scores the entries themselves, and ``v_up`` is applied to
+    the weighted sum of entries.  A wider chunk (prefill) attends expanded:
+    each row's entries are decompressed per head, one row at a time so the
+    per-head keys, values and scores of only one row are live."""
+    dt = q_nope.dtype
+    r = cfg.kv_lora_rank
+    scale = mla_softmax_scale(cfg)
+    valid = (pos_ids >= 0)[:, None, :] & \
+        (pos_ids[:, None, :] <= positions[..., None])  # (B,S,T)
+    if q_nope.shape[1] == 1:
+        q_c = jnp.einsum("bshk,rhk->bshr", q_nope, p["k_up"].astype(dt))
+        q_cat = jnp.concatenate([q_c, q_rope], -1)  # (B,S,H,r+rope)
+        scores = jnp.einsum("bshr,btr->bhst", q_cat, latent,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(valid[:, None], scores, NEG_INF)  # (B,H,S,T)
+        w = jax.nn.softmax(scores, -1).astype(dt)
+        ctx = jnp.einsum("bhst,btr->bshr", w, latent[..., :r])  # (B,S,H,r)
+        return jnp.einsum("bshr,rhk->bshk", ctx, p["v_up"].astype(dt))
+
+    def row(args):
+        qn, qr, lat, ok = args  # (S,H,nope), (S,H,rope), (T,r+rope), (S,T)
+        k, v = _mla_expand(p, lat, cfg, dt)  # (T,H,nope+rope), (T,H,v)
+        q = jnp.concatenate([qn, qr], -1)
+        scores = jnp.einsum("shd,thd->hst", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(ok[None], scores, NEG_INF)
+        w = jax.nn.softmax(scores, -1).astype(dt)
+        return jnp.einsum("hst,thd->shd", w, v)
+
+    return jax.lax.map(row, (q_nope, q_rope, latent, valid))
 
 
 def mla_decode(p, x, cache, pos, cfg: ModelConfig, plan: Plan, n_valid=None):
-    """Absorbed decode: score directly against compressed cache (TPU-native).
-
-    Ragged like :func:`gqa_decode`: ``pos`` scalar or (B,), S >= 1, per-row
-    append at each row's own offset (full-length cache, no ring).
+    """Ragged decode/extend against a slot-contiguous cache: ``pos`` scalar
+    or (B,), S >= 1, per-row append at each row's own offset (full-length
+    cache, no ring); see :func:`_mla_attend` for the two attention forms.
     """
     B, S, _ = x.shape
-    dt = x.dtype
-    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     positions = decode_positions(pos, B, S)  # (B,S)
     q_nope, q_rope = _mla_q(p, x, cfg, positions)  # (B,S,H,nope/rope)
-    c_new, kr_new = _mla_ckv(p, x, cfg, positions)  # (B,S,r), (B,S,rope)
+    new = _mla_latent(p, x, cfg, positions)  # (B,S,r+rope)
     start = positions[:, 0]
-    c_kv = _row_update(cache["c_kv"], c_new, start)
-    k_rope = _row_update(cache["k_rope"], kr_new, start)
+    latent = _row_update(cache["latent"], new, start)
     pos_ids = _row_update(cache["pos_ids"], _new_pos_ids(positions, n_valid),
                           start)  # (B,T)
-    # absorb k_up into q: (B,S,H,r)
-    q_c = jnp.einsum("bshk,rhk->bshr", q_nope, p["k_up"].astype(dt))
-    scores = (jnp.einsum("bshr,btr->bhst", q_c, c_kv,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bshk,btk->bhst", q_rope, k_rope,
-                           preferred_element_type=jnp.float32))
-    scores = scores / jnp.sqrt(nope + rope_d).astype(jnp.float32)
-    valid = (pos_ids >= 0)[:, None, :] & \
-        (pos_ids[:, None, :] <= positions[..., None])  # (B,S,T)
-    scores = jnp.where(valid[:, None], scores, NEG_INF)  # (B,H,S,T)
-    w = jax.nn.softmax(scores, -1).astype(dt)
-    ctx_c = jnp.einsum("bhst,btr->bshr", w, c_kv)  # (B,S,H,r)
-    o = jnp.einsum("bshr,rhk->bshk", ctx_c, p["v_up"].astype(dt))  # absorbed v_up
-    o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(dt))
-    return o, {"c_kv": c_kv, "k_rope": k_rope, "pos_ids": pos_ids}
+    o = _mla_attend(p, q_nope, q_rope, latent, pos_ids, positions, cfg)
+    o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(x.dtype))
+    return o, {"latent": latent, "pos_ids": pos_ids}
 
 
-def mla_seed_cache(cache, kv, prefill_len: int, lengths=None):
-    c_kv, k_rope = kv
-    B, S = c_kv.shape[0], c_kv.shape[1]
+def mla_decode_paged(p, x, pool, layer, bt, pos, cfg: ModelConfig,
+                     plan: Plan, n_valid=None):
+    """:func:`mla_decode` against a page pool of cache entries: ``latent``
+    (L, P, page, r+rope) and ``pos_ids`` (L, P, page), or one layer's
+    (P, page, ...) with ``layer`` None.  The chunk's valid entries are
+    written into their pages (``kv_write``), then the layer's pages are
+    read through the block table ``bt`` (``kv_read``) and attended as
+    :func:`mla_decode` does."""
+    B, S, _ = x.shape
+    positions = decode_positions(pos, B, S)
+    q_nope, q_rope = _mla_q(p, x, cfg, positions)
+    pool = _paged_write(pool, layer, bt, positions,
+                        _new_pos_ids(positions, n_valid),
+                        {"latent": _mla_latent(p, x, cfg, positions)})
+    view = _paged_read(pool, layer, bt)
+    o = _mla_attend(p, q_nope, q_rope, view["latent"], view["pos_ids"],
+                    positions, cfg)
+    o = jnp.einsum("bshd,hdk->bsk", o, p["wo"].astype(x.dtype))
+    return o, pool
+
+
+def mla_seed_cache(cache, latent, prefill_len: int, lengths=None):
+    B, S = latent.shape[0], latent.shape[1]
     pos = jnp.arange(S, dtype=jnp.int32)
     pos2 = jnp.broadcast_to(pos[None], (B, S))
     if lengths is not None:
         pos2 = jnp.where(pos2 < jnp.asarray(lengths, jnp.int32)[:, None],
                          pos2, -1)
     return {
-        "c_kv": jax.lax.dynamic_update_slice_in_dim(cache["c_kv"], c_kv, 0, 1),
-        "k_rope": jax.lax.dynamic_update_slice_in_dim(cache["k_rope"], k_rope, 0, 1),
+        "latent": jax.lax.dynamic_update_slice_in_dim(cache["latent"], latent,
+                                                      0, 1),
         "pos_ids": jax.lax.dynamic_update_slice(cache["pos_ids"], pos2, (0, 0)),
     }

@@ -6,6 +6,7 @@ parameters are stored in ``cfg.param_dtype``.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -125,10 +126,41 @@ def unembed_apply(p, x, cfg: ModelConfig, plan: Plan):
 
 # --- rotary -------------------------------------------------------------------
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_attn_mscale(cfg: ModelConfig) -> float:
+    """YaRN's ``m``: the softmax scale of an attention with YaRN rope is
+    ``q_head_dim ** -0.5 * m ** 2`` (DeepSeek-V2's modelling code; 1 without
+    YaRN or without ``mscale_all_dim``)."""
+    y = cfg.yarn
+    if not y or not y.get("mscale_all_dim"):
+        return 1.0
+    return _yarn_mscale(y["factor"], y["mscale_all_dim"])
+
+
 def rope_freqs(cfg: ModelConfig, dim: Optional[int] = None):
     d = dim or cfg.head_dim
     inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    return inv  # (d/2,)
+    y = cfg.yarn
+    if y is None:
+        return inv  # (d/2,)
+    # YaRN: frequencies that turn fewer than beta_slow times over the
+    # original context are interpolated (divided by the factor), those that
+    # turn more than beta_fast times are kept, and a linear ramp over the
+    # dimensions between blends the two
+    orig, base = y["original_max_position_embeddings"], cfg.rope_theta
+
+    def dim_at(rotations):
+        return (d * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(dim_at(y["beta_fast"])), 0)
+    high = min(math.ceil(dim_at(y["beta_slow"])), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return inv / y["factor"] * ramp + inv * (1.0 - ramp)
 
 
 def apply_rope(x, positions, cfg: ModelConfig, dim: Optional[int] = None):
@@ -139,6 +171,12 @@ def apply_rope(x, positions, cfg: ModelConfig, dim: Optional[int] = None):
     inv = rope_freqs(cfg, d)
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, d/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    y = cfg.yarn
+    if y is not None:  # cos and sin carry mscale / mscale_all_dim
+        ratio = (_yarn_mscale(y["factor"], y.get("mscale", 1.0))
+                 / _yarn_mscale(y["factor"], y.get("mscale_all_dim", 0.0)))
+        if ratio != 1.0:
+            cos, sin = cos * ratio, sin * ratio
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -85,19 +85,21 @@ class Model:
             return mm.whisper_decode(params, tokens, cache, pos, cfg, plan,
                                      n_valid=n_valid)
         return tf.lm_decode(params, tokens, cache, pos, cfg, plan,
-                            n_valid=n_valid)
+                            n_valid=n_valid)[:2]
 
     @property
     def decodes_in_pool(self) -> bool:
-        """Whether :meth:`decode_paged` applies: every cache of the stack is
-        a full-length GQA cache."""
+        """Whether :meth:`decode_paged` applies: every cache of the stack
+        holds full-length attention entries (GQA K/V or MLA latents)."""
         return tf.paged_decode_applies(self.cfg)
 
     def decode_paged(self, params, tokens, pool, bt, pos, n_valid=None):
-        """:meth:`decode` against a page pool of full-length GQA caches
-        (``pool`` as ``PagedKVCacheManager`` holds it, ``bt`` the block
-        tables): K/V stay in their pages, each layer writes only the new
-        entries.  Returns (logits, updated pool)."""
+        """:meth:`decode` against a page pool of full-length attention
+        caches (``pool`` as ``PagedKVCacheManager`` holds it, ``bt`` the
+        block tables): entries stay in their pages, each layer writes only
+        the new ones.  Returns (logits, updated pool, stats), ``stats`` the
+        expert layers' counters summed over layers (``routed_local``,
+        ``experts_touched``; empty without experts)."""
         return tf.lm_decode(params, tokens, pool, pos, self.cfg, self.plan,
                             n_valid=n_valid, bt=bt)
 

@@ -183,8 +183,8 @@ def vlm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
 
         def inner(x, plc):
             lp, lc = plc
-            x, lc = attn_block_decode(lp, x, lc, pos, cfg, plan,
-                                      n_valid=n_valid)
+            x, lc, _ = attn_block_decode(lp, x, lc, pos, cfg, plan,
+                                         n_valid=n_valid)
             return x, lc
 
         x, sc = jax.lax.scan(inner, x, (sp, sc))

@@ -69,14 +69,21 @@ def attn_block_apply(p, x, cfg, plan, positions=None, collect_kv=False):
     return (x, aux, kv) if collect_kv else (x, aux)
 
 
-def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None, pages=None):
+def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None, pages=None,
+                      experts=None):
     """One block's decode step; ``pages`` = (layer, block table) runs its
-    attention against the page pool ``cache``
-    (:func:`attn.gqa_decode_paged`)."""
+    attention against the page pool ``cache`` (:func:`attn.gqa_decode_paged`,
+    :func:`attn.mla_decode_paged`).  An expert block runs the dropless
+    layer over the experts this chip holds; ``experts`` = (every layer's
+    expert weights, layer) hands it the whole stack
+    (:func:`moe_lib.moe_dropless`).  Returns (x, cache, stats): the expert
+    layer's counters, empty for a dense block."""
     h = L.norm_apply(p["ln1"], x, cfg)
     if pages is not None:
-        a, cache = attn.gqa_decode_paged(p["attn"], h, cache, *pages, pos,
-                                         cfg, plan, n_valid=n_valid)
+        paged = (attn.mla_decode_paged if cfg.attn_type == "mla"
+                 else attn.gqa_decode_paged)
+        a, cache = paged(p["attn"], h, cache, *pages, pos, cfg, plan,
+                         n_valid=n_valid)
     elif cfg.attn_type == "mla":
         a, cache = attn.mla_decode(p["attn"], h, cache, pos, cfg, plan,
                                    n_valid=n_valid)
@@ -85,11 +92,20 @@ def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None, pages=None):
                                    n_valid=n_valid)
     x = x + a
     h = L.norm_apply(p["ln2"], x, cfg)
+    stats = {}
     if "moe" in p:
-        m, _ = moe_lib.moe_apply(p["moe"], h, cfg, plan)
+        S = x.shape[1]
+        valid = (None if n_valid is None else
+                 jnp.arange(S)[None] < jnp.asarray(n_valid)[:, None])
+        if experts is None:
+            m, stats = moe_lib.moe_dropless(p["moe"], h, cfg, plan, valid)
+        else:
+            m, stats = moe_lib.moe_dropless({**p["moe"], **experts[0]}, h,
+                                            cfg, plan, valid,
+                                            layer=experts[1])
     else:
         m = L.mlp_apply(p["mlp"], h, cfg, plan)
-    return x + m, cache
+    return x + m, cache, stats
 
 
 def ssm_block_params(cfg, plan):
@@ -328,7 +344,7 @@ def _seed_attn_cache(cfg, plan, kv, max_len, dtype, batch, lengths=None):
     """Build a seeded per-layer cache directly from prefill K/V."""
     if cfg.attn_type == "mla":
         zero = attn.mla_cache_init(cfg, plan, batch, max_len, dtype)
-        return attn.mla_seed_cache(zero, kv, kv[0].shape[1], lengths=lengths)
+        return attn.mla_seed_cache(zero, kv, kv.shape[1], lengths=lengths)
     zero = attn.gqa_cache_init(cfg, plan, batch, max_len, dtype)
     return attn.gqa_seed_cache(zero, kv, kv[0].shape[1], lengths=lengths)
 
@@ -412,62 +428,81 @@ def lm_prefill(params, tokens, cfg: ModelConfig, plan: Plan,
 # decode step
 # =============================================================================
 
+def _split_experts(stack):
+    """(the layer stack's params without its routed experts' weights, those
+    weights stacked over the layers or None): the scan hands each layer its
+    slice of the rest, and the expert layer the whole stack of experts
+    (:func:`moe_lib.moe_dropless`)."""
+    if "moe" not in stack:
+        return stack, None
+    moe = dict(stack["moe"])
+    experts = {n: moe.pop(n) for n in ("wg", "wu", "wd")}
+    return {**stack, "moe": moe}, experts
+
+
 def paged_decode_applies(cfg: ModelConfig) -> bool:
-    """True when every cache of the stack is a full-length GQA cache (one
-    layer stack of ``k``/``v``/``pos_ids``: no MLA, sliding window or dense
-    prefix blocks), so :func:`lm_decode` can run against a page pool."""
-    return (cfg.family in ("dense", "moe") and not cfg.first_k_dense
-            and cfg.attn_type != "mla" and not cfg.sliding_window)
+    """True when every cache of the stack holds full-length attention
+    entries (GQA K/V or MLA latents; dense prefix blocks included, no
+    sliding window), so :func:`lm_decode` can run against a page pool."""
+    return cfg.family in ("dense", "moe") and not cfg.sliding_window
 
 
 def lm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
               n_valid=None, bt=None):
-    """tokens:(B,S) -> logits:(B,S,V); functional cache update.
+    """tokens:(B,S) -> (logits (B,S,V), cache, stats); functional cache
+    update.  ``stats`` sums the expert layers' counters over the layers
+    (``moe.moe_dropless``; empty without experts).
 
     ``pos`` may be a scalar or a (B,) vector of per-slot positions, and S may
     exceed 1 (chunked-prefill extend, attention families); ``n_valid`` (B,)
     marks real tokens per row for ragged extends.
 
-    With block tables ``bt`` (B, W), ``cache`` is a page pool
-    ``{"stack": {"k", "v", "pos_ids"}}`` with pages on axis 1 (only where
+    With block tables ``bt`` (B, W), ``cache`` is a page pool shaped as
+    :func:`lm_cache` builds it with pages on the batch axis (only where
     :func:`paged_decode_applies`): each layer writes its new entries into
-    their pages and reads its own pages through ``bt``.  The pool is carried
-    through the layer scan, not returned as its ys (which would stack a
-    copy), and comes back updated.
+    their pages and reads its own pages through ``bt``.  The stack's pool is
+    carried through the layer scan, not returned as its ys (which would
+    stack a copy), and comes back updated.
     """
     if bt is not None and not paged_decode_applies(cfg):
-        raise ValueError("paged decode needs a stack of full-length GQA "
-                         "caches (no MLA, sliding window or dense prefix)")
+        raise ValueError("paged decode needs full-length GQA or MLA caches "
+                         "(no sliding window or recurrent state)")
     x = L.embed_apply(params["embed"], tokens, cfg, plan)
+    stats = {}
 
     if cfg.family in ("dense", "moe"):
-        for i in range(cfg.first_k_dense):
-            x, cache[f"dense{i}"] = attn_block_decode(
+        for i in range(cfg.first_k_dense):  # dense MLP blocks: no counters
+            x, cache[f"dense{i}"], _ = attn_block_decode(
                 params["blocks"][f"dense{i}"], x, cache[f"dense{i}"], pos, cfg,
-                plan, n_valid=n_valid)
+                plan, n_valid=n_valid,
+                pages=None if bt is None else (None, bt))
 
-        stack = params["blocks"]["stack"]
+        stack, experts = _split_experts(params["blocks"]["stack"])
+        n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        layers = jnp.arange(n, dtype=jnp.int32)
         if bt is None:
             def body(x, pc):
-                lp, lc = pc
-                x, lc = attn_block_decode(lp, x, lc, pos, cfg, plan,
-                                          n_valid=n_valid)
-                return x, lc
+                lp, lc = pc[:2]
+                x, lc, st = attn_block_decode(
+                    lp, x, lc, pos, cfg, plan, n_valid=n_valid,
+                    experts=experts and (experts, pc[2]))
+                return x, (lc, st)
 
-            x, new_stack = jax.lax.scan(body, x, (stack, cache["stack"]))
+            x, (new_stack, sts) = jax.lax.scan(
+                body, x, (stack, cache["stack"])
+                + ((layers,) if experts else ()))
         else:
             def body(carry, pl):
                 x, pool = carry
                 lp, layer = pl
-                x, pool = attn_block_decode(lp, x, pool, pos, cfg, plan,
-                                            n_valid=n_valid,
-                                            pages=(layer, bt))
-                return (x, pool), None
+                x, pool, st = attn_block_decode(
+                    lp, x, pool, pos, cfg, plan, n_valid=n_valid,
+                    pages=(layer, bt), experts=experts and (experts, layer))
+                return (x, pool), st
 
-            n = jax.tree_util.tree_leaves(stack)[0].shape[0]
-            (x, new_stack), _ = jax.lax.scan(
-                body, (x, cache["stack"]),
-                (stack, jnp.arange(n, dtype=jnp.int32)))
+            (x, new_stack), sts = jax.lax.scan(
+                body, (x, cache["stack"]), (stack, layers))
+        stats = {k: jnp.sum(v) for k, v in sts.items()}
         cache = {**cache, "stack": new_stack}
     elif cfg.family == "ssm":
         def body(x, pc):
@@ -490,8 +525,8 @@ def lm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
                 return x, lc
 
             x, gc = jax.lax.scan(inner, x, (gp, gc))
-            x, ac = attn_block_decode(bp["shared_attn"], x, ac, pos, cfg, plan,
-                                      n_valid=n_valid)
+            x, ac, _ = attn_block_decode(bp["shared_attn"], x, ac, pos, cfg,
+                                         plan, n_valid=n_valid)
             return x, (gc, ac)
 
         x, (new_groups, new_attn) = jax.lax.scan(
@@ -507,5 +542,5 @@ def lm_decode(params, tokens, cache, pos, cfg: ModelConfig, plan: Plan,
 
     x = L.norm_apply(params["final_ln"], x, cfg)
     logits = L.unembed_apply(params["embed"], x, cfg, plan)
-    return logits, cache
+    return logits, cache, stats
 

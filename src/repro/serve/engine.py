@@ -15,13 +15,14 @@ Cache state lives in :class:`~repro.serve.cache.KVCacheManager` (or, with
 pages behind per-slot block tables): per-slot positions, page accounting,
 slot recycling (freed rows/pages are invalidated via ``pos_ids = -1`` and
 reused without growing the arrays).  On the paged path, when every cache
-of the stack is a full-length GQA cache (``Model.decodes_in_pool``), K/V
-never leaves the page pool: the fused step hands the donated pool and the
-block tables to ``Model.decode_paged``, whose layers each write the tick's
-new entries into their pages and read their own pages through the tables.
-Other layouts (ring caches of sliding-window models, MLA, dense prefix
-blocks) gather a slot-contiguous logical cache through the block tables,
-run ``Model.decode`` and scatter pages back.  Either way it is one jit with outputs bitwise identical to the
+of the stack holds full-length attention entries (GQA K/V or MLA latents,
+dense prefix blocks included: ``Model.decodes_in_pool``), the entries never
+leave the page pool: the fused step hands the donated pool and the block
+tables to ``Model.decode_paged``, whose layers each write the tick's new
+entries into their pages and read their own pages through the tables.
+Ring caches of sliding-window models gather a slot-contiguous logical cache
+through the block tables, run ``Model.decode`` and scatter pages back.
+Either way it is one jit with outputs bitwise identical to the
 contiguous manager, and admission/extension run at page granularity off
 the actual free list, so churn that would fragment contiguous rows costs
 nothing.
@@ -57,11 +58,15 @@ so they land on the same clock as the device's operations:
 ``.upload`` (key split, tick arrays, block table; stat ``bt_sent``),
 ``.dispatch`` (the fused call; stats ``width``, ``prefill``, ``decode``,
 ``prompt_tokens``, ``kv_pool``: 1 on the in-pool path), ``.sync`` (the
-host copy of the output), ``.commit`` (advance and append) and
+host copy of the output), ``.commit`` (advance and append; on the
+in-pool path of an expert model, stats ``routed_local`` and
+``experts_touched``, the tick's expert counters, also totalled on
+``Engine``) and
 ``.release`` (pages returned, stat ``pages``; nested in ``commit``, or in
 ``compose`` when preemption frees a slot).  Inside the fused step,
 ``jax.named_scope`` marks ``decode`` (with ``kv_write`` and ``kv_read`` in
-each layer on the in-pool path), ``sample``, and on the gather path
+each layer on the in-pool path, and ``moe_route``, ``moe_experts`` and
+``moe_shared`` in each expert layer), ``sample``, and on the gather path
 ``kv_gather`` and ``kv_scatter``, in every device op's metadata.
 Each :class:`Request` carries ``perf_counter`` stamps of its submission,
 first admission, first token and completion.
@@ -201,26 +206,31 @@ class Engine:
 
         self._kv_pool = self._paged and model.decodes_in_pool
         self.kv_pool_ticks = 0  # fused ticks run on the in-pool path
+        # the in-pool step's expert counters, summed over layers and ticks:
+        # picks that landed on experts this chip holds, and held experts
+        # given at least one token
+        self.routed_local = 0
+        self.experts_touched = 0
         if self._kv_pool:
             mgr = self.mgr
 
             def decode_paged(params, tokens, pool, bt, pos, n_valid):
                 with jax.named_scope("decode"):
-                    logits, out = model.decode_paged(
+                    logits, out, stats = model.decode_paged(
                         params, tokens, pool, bt, pos, n_valid=n_valid)
                 # the layers wrote their entries in place; the manager's
                 # write-back hands the stepped pool on as it is
-                return logits, mgr.scatter_all(pool, out, None)
+                return logits, mgr.scatter_all(pool, out, None), stats
 
             def fused(params, pool, bt, tokens, pos, n_valid, key):
-                logits, pool = decode_paged(params, tokens, pool, bt, pos,
-                                            n_valid)
-                return pick(logits, n_valid, key), pool
+                logits, pool, stats = decode_paged(params, tokens, pool, bt,
+                                                   pos, n_valid)
+                return pick(logits, n_valid, key), pool, stats
 
             def fused_spec(params, pool, bt, tokens, pos, n_valid, key):
-                logits, pool = decode_paged(params, tokens, pool, bt, pos,
-                                            n_valid)
-                return verify_rows(logits), pool
+                logits, pool, stats = decode_paged(params, tokens, pool, bt,
+                                                   pos, n_valid)
+                return verify_rows(logits), pool, stats
         elif self._paged:
             mgr = self.mgr
 
@@ -235,20 +245,21 @@ class Engine:
             def fused(params, pool, bt, inv, tokens, pos, n_valid, key):
                 logits, cache = decode(params, tokens, gather(pool, bt), pos,
                                        n_valid)
-                return pick(logits, n_valid, key), scatter(pool, cache, inv)
+                return (pick(logits, n_valid, key),
+                        scatter(pool, cache, inv), {})
 
             def fused_spec(params, pool, bt, inv, tokens, pos, n_valid, key):
                 logits, cache = decode(params, tokens, gather(pool, bt), pos,
                                        n_valid)
-                return verify_rows(logits), scatter(pool, cache, inv)
+                return verify_rows(logits), scatter(pool, cache, inv), {}
         else:
             def fused(params, cache, tokens, pos, n_valid, key):
                 logits, cache = decode(params, tokens, cache, pos, n_valid)
-                return pick(logits, n_valid, key), cache
+                return pick(logits, n_valid, key), cache, {}
 
             def fused_spec(params, cache, tokens, pos, n_valid, key):
                 logits, cache = decode(params, tokens, cache, pos, n_valid)
-                return verify_rows(logits), cache
+                return verify_rows(logits), cache, {}
 
         # the paged step donates the pool: its writes then update the page
         # buffers in place instead of copying the whole pool
@@ -258,10 +269,11 @@ class Engine:
         if warmup:
             self._warmup()
 
-    def _run_fused(self, fn, plan: sched.TickPlan) -> np.ndarray:
+    def _run_fused(self, fn, plan: sched.TickPlan) -> Tuple[np.ndarray, dict]:
         """One fused device step over the plan (on the paged path: in the
         pool, or gather -> decode -> scatter) under the upload, dispatch
-        and sync spans; returns the host copy of the sampled output."""
+        and sync spans; returns the host copy of the sampled output and the
+        tick's expert counters (empty where the step has none)."""
         with span("serve.engine.upload",
                   bt_sent=int(self._paged and self._bt_stale())):
             self.key, key = jax.random.split(self.key)
@@ -276,14 +288,19 @@ class Engine:
                   prompt_tokens=sum(len(w.tokens) for w in prefill),
                   kv_pool=int(self._kv_pool)):
             if self._paged:
-                out, self.mgr.pool = fn(self.params, self.mgr.pool, *tables,
-                                        toks, pos, nv, key)
+                out, self.mgr.pool, stats = fn(self.params, self.mgr.pool,
+                                               *tables, toks, pos, nv, key)
                 self.kv_pool_ticks += self._kv_pool
             else:
-                out, self.mgr.cache = fn(self.params, self.mgr.cache,
-                                         toks, pos, nv, key)
+                out, self.mgr.cache, stats = fn(self.params, self.mgr.cache,
+                                                toks, pos, nv, key)
         with span("serve.engine.sync"):
-            return np.asarray(out)  # the tick's single host sync
+            # the tick's single host sync; the counters come with the output
+            out, stats = jax.device_get((out, stats))
+        stats = {k: int(v) for k, v in stats.items()}
+        self.routed_local += stats.get("routed_local", 0)
+        self.experts_touched += stats.get("experts_touched", 0)
+        return out, stats
 
     def _bt_stale(self) -> bool:
         """True when the host block table differs from its device copy."""
@@ -318,9 +335,9 @@ class Engine:
             if self._paged:
                 # the pool is donated into the jit — rebind the returned
                 # buffer or the manager would hold a deleted array
-                _, self.mgr.pool = fn(self.params, self.mgr.pool,
-                                      *self._bt_device(), toks, zero, zero,
-                                      self.key)
+                _, self.mgr.pool, _ = fn(self.params, self.mgr.pool,
+                                         *self._bt_device(), toks, zero,
+                                         zero, self.key)
             else:
                 fn(self.params, self.mgr.cache, toks, zero, zero, self.key)
         if self._paged:
@@ -547,11 +564,11 @@ class Engine:
         if plan is None:
             return 0
         if spec:
-            rows = self._run_fused(self._fused_spec, plan)  # (B, k+1)
-            with span("serve.engine.commit"):
+            rows, stats = self._run_fused(self._fused_spec, plan)  # (B, k+1)
+            with span("serve.engine.commit", **stats):
                 return self._commit_spec(plan, rows)
-        nxt = self._run_fused(self._fused, plan)
-        with span("serve.engine.commit"):
+        nxt, stats = self._run_fused(self._fused, plan)
+        with span("serve.engine.commit", **stats):
             return self._commit(plan, nxt)
 
     def _commit(self, plan: sched.TickPlan, nxt: np.ndarray) -> int:

@@ -5,10 +5,11 @@ never a numerics change — paged replay is bitwise the contiguous replay
 (dense + SWA), the speculative accepted prefix is bitwise the greedy
 sequence, and the free-list allocator admits strictly more concurrent
 work than contiguous slots at the same page budget (the churn workload).
-Full-length GQA stacks keep K/V in the page pool (``TestInPoolStep``);
-ring and latent caches keep the gather/scatter step.
+Full-length GQA and MLA stacks keep their entries in the page pool
+(``TestInPoolStep``); ring caches keep the gather/scatter step.
 """
 import functools
+import hashlib
 
 import jax
 import numpy as np
@@ -496,12 +497,13 @@ class TestInPoolStep:
         for layer in ids[:, 0]:
             assert sorted(layer[layer >= 0]) == list(range(55))
 
-    @pytest.mark.parametrize("layout", ["swa", "mla"])
+    @pytest.mark.parametrize("layout", ["swa"])
     def test_ring_and_latent_caches_keep_the_gather_path(self, request,
                                                          layout):
-        """Sliding-window (ring) and MLA stacks do not hold full-length GQA
-        pages: they keep gather -> decode -> scatter, bitwise the
-        contiguous engine, and the in-pool entry point refuses them."""
+        """Sliding-window (ring) stacks do not hold full-length pages: they
+        keep gather -> decode -> scatter, bitwise the contiguous engine,
+        and the in-pool entry point refuses them.  (MLA latents moved into
+        the pool: ``test_latent_pool_bitwise_contiguous``.)"""
         import jax.numpy as jnp
         cfg, model, params = request.getfixturevalue(layout)
         _, ref = _outs(cfg, model, params, n_req=3, max_new=6)
@@ -513,3 +515,69 @@ class TestInPoolStep:
         with pytest.raises(ValueError, match="full-length GQA"):
             model.decode_paged(params, jnp.zeros((2, 1), jnp.int32),
                                eng.mgr.pool, bt, jnp.zeros(2, jnp.int32))
+
+    @pytest.mark.parametrize("speculate", [0, 3])
+    def test_latent_pool_bitwise_contiguous(self, mla, speculate):
+        """DeepSeek-V2's latents (its dense first layer included) stay in
+        the page pool on every tick, and the served tokens are bitwise the
+        contiguous engine's: the same absorbed decode and expanded prefill
+        over the same entries."""
+        cfg, model, params = mla
+        _, ref = _serve_waves(model, params, cfg)
+        eng, got = _serve_waves(model, params, cfg, paged=True,
+                                speculate=speculate)
+        assert got == ref
+        assert eng.kv_pool_ticks == eng.ticks > 0
+        assert eng.routed_local > 0 and eng.experts_touched > 0
+        assert eng.mgr.pages_in_use == eng.mgr.recount_pages() == 0
+
+
+@pytest.fixture(scope="module")
+def qwen3():
+    cfg = registry.get("qwen3-1.7b").reduced()
+    model = Model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+class TestGqaStepPinned:
+    """The GQA in-pool step is the program it was before MLA moved into the
+    pool: its lowered StableHLO (without debug info) and what it serves,
+    for the qwen3 preset, hash as they did on the tree before that change
+    (JAX 0.9.0, CPU)."""
+
+    PROGRAM = {1: "459b23d6b95136f9cd646cf9907a7a5990fd6f4f601a15f37201951362aaf45a",
+               8: "666208b439e0c7d3e98de5395c956784fc201aaa8671c655253cd3dea6e2290d"}
+    POOL = "233a17436bec541e723a129a4dcbb1bb56c3154c7415eaa248aaba396805b393"
+    TOKENS = {0: (187, 187, 187, 200, 10, 181, 187, 187, 200), 1: (180,) * 14,
+              2: (169, 219, 219, 247, 6, 50),
+              3: (169, 169, 169) + (239,) * 7, 4: (209, 227, 208, 58, 58)}
+
+    @pytest.mark.parametrize("width", [1, 8])
+    def test_program_unchanged(self, qwen3, width):
+        import jax.numpy as jnp
+        _, model, _ = qwen3
+        eng = Engine(model, None, batch_slots=3, max_len=64, page_size=8,
+                     prefill_chunk=8, paged=True, eos_id=-1, warmup=False)
+
+        def shape(tree):
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+        slots = jax.ShapeDtypeStruct((3,), jnp.int32)
+        text = eng._fused.lower(
+            model.abstract_params(), shape(eng.mgr.pool),
+            *shape(eng._bt_device()),
+            jax.ShapeDtypeStruct((3, width), jnp.int32), slots, slots,
+            shape(eng.key)).as_text(debug_info=False)
+        assert hashlib.sha256(text.encode()).hexdigest() == \
+            self.PROGRAM[width]
+
+    def test_served_tokens_and_pool_bitwise(self, qwen3):
+        cfg, model, params = qwen3
+        eng, got = _serve_waves(model, params, cfg, paged=True)
+        assert got == self.TOKENS
+        assert eng.kv_pool_ticks == eng.ticks and eng.routed_local == 0
+        h = hashlib.sha256()
+        for name in ("k", "v", "pos_ids"):
+            h.update(np.asarray(eng.mgr.pool["stack"][name]).tobytes())
+        assert h.hexdigest() == self.POOL

@@ -180,6 +180,57 @@ def test_gather_path_op_metadata_names_its_layers(swa, spec):
         assert not any(f"/{scope}/" in n for n in names), scope
 
 
+@pytest.fixture(scope="module")
+def mla():
+    cfg = registry.get("deepseek-v2-236b").reduced()
+    model = Model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(2))
+
+
+def test_latent_expert_step_names_its_layers(mla):
+    """DeepSeek-V2 (latent attention, expert layers) decodes in the pool:
+    its latents' reads and writes sit under ``kv_read`` and ``kv_write``,
+    like GQA K/V, and each expert layer's parts under ``moe_route``,
+    ``moe_experts`` and ``moe_shared``."""
+    eng = _engine(mla)
+    assert eng._kv_pool
+    names = _fused_op_names(eng, False)
+    for scope in ("decode", "kv_write", "kv_read", "moe_route",
+                  "moe_experts", "moe_shared", "sample"):
+        assert any(f"/{scope}/" in n for n in names), scope
+    for scope in ("kv_gather", "kv_scatter"):
+        assert not any(f"/{scope}/" in n for n in names), scope
+
+
+def test_commit_spans_carry_the_expert_counters(mla, tmp_path):
+    """Each in-pool tick of an expert model returns its counters with its
+    output, read at the tick's one sync: the commit span's
+    ``routed_local`` and ``experts_touched``, which add up to the engine's
+    totals; a dense model's commit spans carry none."""
+    cfg = mla[0]
+    eng = _engine(mla)
+    _, tr = _serve(eng, _requests(cfg), str(tmp_path))
+    commits = tr.spans_named("serve.engine.commit")
+    dispatch = tr.spans_named("serve.engine.dispatch")
+    assert commits and all(d.stats["kv_pool"] == 1 for d in dispatch)
+    assert sum(c.stats["routed_local"] for c in commits) == \
+        eng.routed_local > 0
+    assert sum(c.stats["experts_touched"] for c in commits) == \
+        eng.experts_touched > 0
+    # at most every held expert in every expert layer, each tick
+    layers = cfg.num_layers - cfg.first_k_dense
+    assert all(0 <= c.stats["experts_touched"] <= cfg.n_held * layers
+               for c in commits)
+
+
+def test_dense_commit_spans_carry_no_counters(dense, tmp_path):
+    eng = _engine(dense)
+    _, tr = _serve(eng, _requests(dense[0]), str(tmp_path))
+    assert all("routed_local" not in c.stats
+               for c in tr.spans_named("serve.engine.commit"))
+    assert eng.routed_local == eng.experts_touched == 0
+
+
 def _fused_op_names(eng, spec):
     """The op names in the compiled fused step's metadata."""
     fn = eng._fused_spec if spec else eng._fused

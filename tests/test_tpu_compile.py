@@ -104,3 +104,30 @@ def test_paged_fused_decode_step_qwen3(one_chip):
     # 1024 positions x 4,096 B), which the gather/scatter step materialised
     assert m.temp_size_in_bytes < 2 * 8 * 1024 * 4096, m.temp_size_in_bytes
     _fits(compiled)
+
+
+def test_paged_fused_decode_step_deepseek_v2(one_chip):
+    """The engine's paged fused step for DeepSeek-V2 at published widths,
+    cut to its dense layer and one expert layer holding 10 of the 160
+    experts, bf16 weights as served, 16 slots of 2048 tokens: the latents
+    stay in the page pool (donated, written in place) and the expert layer
+    compiles its grouped matmuls."""
+    model = Model(registry.get("deepseek-v2-236b").replace(
+        num_layers=2, held_experts=10, param_dtype="bfloat16"))
+    eng = Engine(model, None, batch_slots=16, max_len=2048,
+                 prefill_chunk=256, paged=True, warmup=False)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: _spec(x.shape, x.dtype, one_chip), tree)
+
+    slots = _spec((16,), jnp.int32, one_chip)
+    compiled = eng._fused.lower(
+        on_chip(model.abstract_params()), on_chip(eng.mgr.pool),
+        *on_chip(eng._bt_device()), _spec((16, 1), jnp.int32, one_chip),
+        slots, slots, on_chip(eng.key)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(eng.mgr.pool))
+    assert "ragged-dot" in compiled.as_text()
+    _fits(compiled)
