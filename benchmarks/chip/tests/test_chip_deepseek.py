@@ -140,13 +140,15 @@ def _expert_layer(c, w, start=0, held=None):
     cfg = harness.program_config(c)
     held = cfg.n_held if held is None else held
     canon = {"wg": "gate_proj", "wu": "up_proj", "wd": "down_proj"}
-    p = {k: w[f"moe.experts.{v}"][0, start:start + held]
+    # one expert layer's experts, stacked as the decode scan hands them
+    p = {k: w[f"moe.experts.{v}"][:1, start:start + held]
          for k, v in canon.items()}
     p["router"] = w["moe.gate"][0]
     p["shared"] = {k: w[f"moe.shared_experts.{v}"][0]
                    for k, v in canon.items()}
     model = program(c)
-    return (lambda h: moe.moe_dropless(p, h[None], model.cfg, model.plan)), p
+    return (lambda h: moe.moe_dropless(p, h[None], model.cfg, model.plan,
+                                       0)), p
 
 
 def _reference_layer(w, h, c):
@@ -224,13 +226,52 @@ def test_reference_routes_are_the_gate_picks(f32):
     assert all(len({int(e) // 4 for e in row}) <= 2 for row in r[0])
 
 
+def _upcast():
+    return harness.load_module(os.path.join(S.ROOT, "drivers",
+                                            "serve_upcast.py"),
+                               "bench_driver_serve_upcast")
+
+
+def test_limits_part_program_and_control_by_the_p90():
+    """The configuration's limits on gaps shaped as the chip's readings:
+    the program's (most tokens the reference's best, a tenth a little
+    below it, a flipped expert's wide gap at a few positions) pass; a
+    lower precision's (every position moved, its widest under the guard)
+    fail by the p90 alone; a wrong token fails by the guard."""
+    drv = _upcast()
+    lim = FULL["limits"]
+    prog = np.zeros(1490)
+    prog[:160] = 0.04
+    prog[:15] = 0.5
+    prog[:2] = 2.14
+    ctrl = np.linspace(0.0, 3.0, 1490)
+    assert drv.p90(prog) == pytest.approx(0.04)
+    assert drv.within(prog, lim)
+    assert not drv.within(ctrl, lim)
+    assert drv.within(ctrl, {**lim, "p90_logit_gap": np.inf})
+    assert not drv.within(np.append(prog, 5.0), lim)
+    assert not drv.within(np.zeros(0), lim)
+
+
+def test_check_weights_are_drawn_in_the_served_dtype():
+    """The check's weights stay in the served dtype whatever the serving
+    driver asks for, and only float32 (or nothing) may be asked for."""
+    drv = _upcast()
+    specs = {"a": ((2, 3), ("normal", 1.0))}
+    w = drv._served(specs, 7, "bfloat16", "float32")
+    assert w["a"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(w["a"], np.float32),
+        np.asarray(weights.canonical(specs, 7, "bfloat16", "float32")["a"]))
+    with pytest.raises(AssertionError):
+        drv._served(specs, 7, "bfloat16", "float16")
+
+
 def test_calibration_counts_routing_differences(f32):
     """The calibration's routing count: the program's gate through its
     chunked decode path and the reference's gate agree on every decision
     in float32, and a changed pick on a held expert is counted as one."""
-    drv = harness.load_module(os.path.join(S.ROOT, "drivers",
-                                           "serve_upcast.py"),
-                              "bench_driver_serve_upcast")
+    drv = _upcast()
     c, model, params, w = f32
     seq = (np.arange(37) * 11 % 256).astype(np.int32)
     got = drv.program_routes(model, params, seq, chunk=16)
@@ -366,7 +407,28 @@ def test_cell_result_line(tmp_path, monkeypatch):
         assert result["correct"] is True and result["failed"] == 0
         gap, limit = checks["max_logit_gap"]
         assert 0 <= gap <= 1e-4  # float32 round-off
+        q, limit = checks["p90_logit_gap"]
+        assert 0 <= q <= gap and limit == FULL["limits"]["p90_logit_gap"]
         if not trace:
             assert set(result["metrics"]) == {
                 "output_tokens_per_s", "itl_p95_ms", "setup_s"}
+
+
+def test_cell_is_not_correct_past_the_p90_limit(tmp_path, monkeypatch):
+    """A p90 limit below every reading fails the run, with the widest gap
+    inside its own limit: the run's ``correct`` holds both."""
+    monkeypatch.setitem(PEAKS, "cpu", PEAKS["TPU v5 lite"])
+    root = _tree(tmp_path)
+    path = os.path.join(root, "configs", f"{CONFIG}.json")
+    c = harness.load_json(path)
+    c["limits"]["p90_logit_gap"] = -1.0
+    with open(path, "w") as f:
+        json.dump(c, f)
+    cell = harness.load_cell(S.bench(), CELL, root=root)
+    ctx = harness.Context(cell, 2**33 + 13, 2.0, False, jax.devices()[:1],
+                          time.perf_counter(), harness.CompileClock())
+    result, checks = cell.driver().run(ctx)
+    assert checks["max_logit_gap"][0] <= checks["max_logit_gap"][1]
+    assert checks["p90_logit_gap"][1] == -1.0
+    assert result["correct"] is False and result["failed"] == 0
 

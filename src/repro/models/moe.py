@@ -189,7 +189,7 @@ def moe_apply(p, x, cfg: ModelConfig, plan: Plan) -> Tuple[jax.Array, Dict]:
     return out, losses
 
 
-def moe_dropless(p, x, cfg: ModelConfig, plan: Plan, valid=None, layer=None):
+def moe_dropless(p, x, cfg: ModelConfig, plan: Plan, layer, valid=None):
     """The served expert layer: dropless, over the experts this chip holds.
 
     Routing runs over all ``num_experts``.  The picks that land on held
@@ -200,11 +200,11 @@ def moe_dropless(p, x, cfg: ModelConfig, plan: Plan, valid=None, layer=None):
     padded tails), add nothing here; on one chip there is no exchange.  The
     shared experts run for every token.
 
-    With ``layer``, the expert weights ``wg``/``wu``/``wd`` in ``p`` are
-    every layer's, stacked (L, E, ...), and this layer's experts are its
-    groups of the grouped matmuls over the whole stack: the kernel reads
-    only groups that hold rows, whereas a slice of the stack taken inside
-    a layer scan is copied for it on every call.
+    The expert weights ``wg``/``wu``/``wd`` in ``p`` are every expert
+    layer's, stacked (L, E, ...), and ``layer`` names this one: its experts
+    are its groups of the grouped matmuls over the whole stack.  The kernel
+    reads only groups that hold rows, whereas a slice of the stack taken
+    inside the layer scan would be copied for it on every call.
 
     x: (B,S,D) -> (out, stats): ``routed_local`` counts the held picks of
     valid tokens, ``experts_touched`` the held experts given at least one.
@@ -225,14 +225,11 @@ def moe_dropless(p, x, cfg: ModelConfig, plan: Plan, valid=None, layer=None):
         sizes = jnp.sum(jax.nn.one_hot(e, E, dtype=jnp.int32), axis=0)
         rows = jnp.take(xf, order // k, axis=0)  # (T*k, D), by expert
     with jax.named_scope("moe_experts"):
-        w_ex = {n: p[n].astype(dt) for n in ("wg", "wu", "wd")}
-        groups = sizes
-        if layer is not None:
-            n_layers = w_ex["wg"].shape[0]
-            w_ex = {n: a.reshape((n_layers * E,) + a.shape[2:])
-                    for n, a in w_ex.items()}
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros(n_layers * E, sizes.dtype), sizes, (layer * E,))
+        n_layers = p["wg"].shape[0]
+        w_ex = {n: p[n].astype(dt).reshape((n_layers * E,) + p[n].shape[2:])
+                for n in ("wg", "wu", "wd")}
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros(n_layers * E, sizes.dtype), sizes, (layer * E,))
         g = jax.lax.ragged_dot(rows, w_ex["wg"], groups)
         u = jax.lax.ragged_dot(rows, w_ex["wu"], groups)
         y = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_ex["wd"], groups)

@@ -74,10 +74,11 @@ def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None, pages=None,
     """One block's decode step; ``pages`` = (layer, block table) runs its
     attention against the page pool ``cache`` (:func:`attn.gqa_decode_paged`,
     :func:`attn.mla_decode_paged`).  An expert block runs the dropless
-    layer over the experts this chip holds; ``experts`` = (every layer's
-    expert weights, layer) hands it the whole stack
-    (:func:`moe_lib.moe_dropless`).  Returns (x, cache, stats): the expert
-    layer's counters, empty for a dense block."""
+    layer over the experts this chip holds, given ``experts`` = (every
+    layer's expert weights, this layer's index), as :func:`_split_experts`
+    takes them out of the stack (:func:`moe_lib.moe_dropless`).  Returns
+    (x, cache, stats): the expert layer's counters, empty for a dense
+    block."""
     h = L.norm_apply(p["ln1"], x, cfg)
     if pages is not None:
         paged = (attn.mla_decode_paged if cfg.attn_type == "mla"
@@ -97,12 +98,9 @@ def attn_block_decode(p, x, cache, pos, cfg, plan, n_valid=None, pages=None,
         S = x.shape[1]
         valid = (None if n_valid is None else
                  jnp.arange(S)[None] < jnp.asarray(n_valid)[:, None])
-        if experts is None:
-            m, stats = moe_lib.moe_dropless(p["moe"], h, cfg, plan, valid)
-        else:
-            m, stats = moe_lib.moe_dropless({**p["moe"], **experts[0]}, h,
-                                            cfg, plan, valid,
-                                            layer=experts[1])
+        weights, layer = experts
+        m, stats = moe_lib.moe_dropless({**p["moe"], **weights}, h, cfg,
+                                        plan, layer, valid)
     else:
         m = L.mlp_apply(p["mlp"], h, cfg, plan)
     return x + m, cache, stats

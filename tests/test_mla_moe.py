@@ -102,11 +102,17 @@ class TestGate:
                                    rtol=1e-6)
 
 
-def _layer(cfg, seed=0):
+def _layer(cfg, seed=0, layers=3, layer=1):
+    """An expert layer's params with the routed experts' weights of
+    ``layers`` layers stacked, as the decode scan hands them over, and
+    those of layer ``layer`` alone."""
     plan = make_plan(cfg)
-    p = materialize(moe_lib.moe_params(cfg, plan), jax.random.PRNGKey(seed),
-                    "float32")
-    return plan, p
+    ps = [materialize(moe_lib.moe_params(cfg, plan),
+                      jax.random.PRNGKey(seed + i), "float32")
+          for i in range(layers)]
+    p = {**ps[layer], **{n: jnp.stack([q[n] for q in ps])
+                         for n in ("wg", "wu", "wd")}}
+    return plan, p, ps[layer]
 
 
 class TestDroplessLayer:
@@ -116,15 +122,15 @@ class TestDroplessLayer:
 
     def test_matches_a_dense_sum_over_held_experts(self):
         cfg = self.CFG
-        plan, p = _layer(cfg)
+        plan, p, one = _layer(cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 7, cfg.d_model))
-        out, stats = moe_lib.moe_dropless(p, x, cfg, plan)
+        out, stats = moe_lib.moe_dropless(p, x, cfg, plan, 1)
         h = x.reshape(-1, cfg.d_model)
         w, idx, _, _ = moe_lib.router_topk(h @ p["router"], 3,
                                            **moe_lib.gate_args(cfg))
         want = L.mlp_apply(p["shared"], h, cfg, plan)
         for e in range(4):
-            ex = {n: p[n][e] for n in ("wg", "wu", "wd")}
+            ex = {n: one[n][e] for n in ("wg", "wu", "wd")}
             gate = jnp.sum(jnp.where(idx == 4 + e, w, 0.0), -1)[:, None]
             want = want + gate * L.mlp_apply(ex, h, cfg, plan)
         # float32 throughout; the sums run in another order
@@ -139,11 +145,11 @@ class TestDroplessLayer:
         """A ragged tick's padded tails (outside ``valid``) take no held
         pick and leave the valid rows' outputs as they are."""
         cfg = self.CFG
-        plan, p = _layer(cfg)
+        plan, p, _ = _layer(cfg)
         x = jax.random.normal(jax.random.PRNGKey(2), (2, 5, cfg.d_model))
         valid = jnp.asarray([[1, 1, 0, 0, 0], [1, 1, 1, 1, 1]], bool)
-        out, stats = moe_lib.moe_dropless(p, x, cfg, plan, valid)
-        full, _ = moe_lib.moe_dropless(p, x, cfg, plan)
+        out, stats = moe_lib.moe_dropless(p, x, cfg, plan, 1, valid)
+        full, _ = moe_lib.moe_dropless(p, x, cfg, plan, 1)
         v = np.asarray(valid)
         np.testing.assert_array_equal(np.asarray(out)[v], np.asarray(full)[v])
         _, idx, _, _ = moe_lib.router_topk(

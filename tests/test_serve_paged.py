@@ -497,15 +497,13 @@ class TestInPoolStep:
         for layer in ids[:, 0]:
             assert sorted(layer[layer >= 0]) == list(range(55))
 
-    @pytest.mark.parametrize("layout", ["swa"])
-    def test_ring_and_latent_caches_keep_the_gather_path(self, request,
-                                                         layout):
+    def test_ring_caches_keep_the_gather_path(self, swa):
         """Sliding-window (ring) stacks do not hold full-length pages: they
         keep gather -> decode -> scatter, bitwise the contiguous engine,
-        and the in-pool entry point refuses them.  (MLA latents moved into
-        the pool: ``test_latent_pool_bitwise_contiguous``.)"""
+        and the in-pool entry point refuses them.  (MLA latents are in the
+        pool: ``test_latent_pool_bitwise_contiguous``.)"""
         import jax.numpy as jnp
-        cfg, model, params = request.getfixturevalue(layout)
+        cfg, model, params = swa
         _, ref = _outs(cfg, model, params, n_req=3, max_new=6)
         eng, paged = _outs(cfg, model, params, n_req=3, max_new=6,
                            paged=True)
