@@ -18,6 +18,7 @@ def test_schedule(name):
     mix = harness.load_json(os.path.join(S.ROOT, "mixes", f"{name}.json"))
     eng, arr = mix["engine"], mix["arrivals"]
     assert arr["kind"] == "closed" and arr["clients"] == eng["batch_slots"]
+    assert eng["overlap"] is True  # each tick launched before the last lands
     a = traffic.generate(mix, 4160000011, 50, 1000)
     b = traffic.generate(mix, 2**33 + 3, 50, 1000)
     assert [(len(i.prompt), i.max_new, i.client) for i in a] == \

@@ -47,6 +47,16 @@ Two scheduling paths, picked by model family:
   exact-length prefill per request and scatters the row; decode then joins
   the same fused step.
 
+``overlap=True`` (the in-pool path, without speculation) runs one tick
+ahead of the host: each ``step()`` launches tick n, then syncs and commits
+tick n-1, whose sampled tokens feed tick n's decode rows on the device.
+Composing, uploading and dispatching a tick then happen while the device
+still runs the one before, so they drop out of the gap between tokens.  A
+request's tokens land one step after its tick is launched; the host cannot
+see an EOS before the next tick is launched, so a slot whose request ended
+on EOS runs one tick more, whose token is dropped.  Preemption and
+``drain`` land the tick in flight first.
+
 Control-plane hooks (repro.control, DESIGN.md §3): EVERY ``step()`` emits a
 ``TickSample`` — including admit-only and fully-throttled iterations, so
 queue-depth bursts are visible exactly when ``Throttle`` decisions matter.
@@ -92,6 +102,31 @@ from repro.serve.step import sample
 
 
 @dataclass
+class _Flight:
+    """A tick launched and not yet landed (``overlap=True``): its plan, the
+    request each slot served, and the device's sampled tokens and
+    counters."""
+    plan: sched.TickPlan
+    reqs: dict
+    out: jax.Array
+    stats: dict
+
+    def yields(self, slot: int, req) -> bool:
+        """True when this tick gives ``req`` (still in ``slot``) a token."""
+        if self.reqs.get(slot) is not req:
+            return False
+        w = next(w for w in self.plan.work if w.slot == slot)
+        return w.kind == "decode" or w.completes
+
+    def feeds(self, slot: int, req) -> int:
+        """Prompt tokens this tick feeds ``req`` in ``slot``."""
+        if self.reqs.get(slot) is not req:
+            return 0
+        w = next(w for w in self.plan.work if w.slot == slot)
+        return len(w.tokens) if w.kind == "prefill" else 0
+
+
+@dataclass
 class Request:
     rid: int
     prompt: np.ndarray  # (P,) int32
@@ -120,7 +155,7 @@ class Engine:
                  top_k: int = 0, prefill_chunk: int = 16,
                  page_size: int = 16, expandable: bool = False,
                  paged: bool = False, total_pages: Optional[int] = None,
-                 speculate: int = 0,
+                 speculate: int = 0, overlap: bool = False,
                  seed: int = 0, warmup: bool = True,
                  pool: Optional[HostPagePool] = None):
         self.model = model
@@ -205,6 +240,12 @@ class Engine:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         self._kv_pool = self._paged and model.decodes_in_pool
+        if overlap and (not self._kv_pool or self._spec_k):
+            raise ValueError(
+                "overlap requires the in-pool paged step (paged=True on a "
+                "full-length attention stack) and no speculation")
+        self._overlap = bool(overlap)
+        self._inflight: Optional[_Flight] = None
         self.kv_pool_ticks = 0  # fused ticks run on the in-pool path
         # the in-pool step's expert counters, summed over layers and ticks:
         # picks that landed on experts this chip holds, and held experts
@@ -266,6 +307,15 @@ class Engine:
         donate = (1,) if self._paged else ()
         self._fused = jax.jit(fused, donate_argnums=donate)
         self._fused_spec = jax.jit(fused_spec, donate_argnums=donate)
+
+        @jax.jit
+        def feed(tokens, prev):
+            # a -1 marks a decode row whose input is the token the tick in
+            # flight samples for its slot
+            return jnp.where(tokens < 0, prev[:, None].astype(tokens.dtype),
+                             tokens)
+
+        self._feed = feed
         if warmup:
             self._warmup()
 
@@ -274,10 +324,18 @@ class Engine:
         pool, or gather -> decode -> scatter) under the upload, dispatch
         and sync spans; returns the host copy of the sampled output and the
         tick's expert counters (empty where the step has none)."""
+        return self._sync(*self._launch(fn, plan))
+
+    def _launch(self, fn, plan: sched.TickPlan, prev=None):
+        """Upload the tick's arrays and dispatch the fused step; returns its
+        device output and counters.  ``prev``, the sampled tokens of the
+        tick in flight, fills the plan's -1 decode inputs on the device."""
         with span("serve.engine.upload",
                   bt_sent=int(self._paged and self._bt_stale())):
             self.key, key = jax.random.split(self.key)
             toks = jnp.asarray(plan.tokens)
+            if prev is not None:
+                toks = self._feed(toks, prev)
             pos = jnp.asarray(plan.pos)
             nv = jnp.asarray(plan.n_valid)
             if self._paged:
@@ -294,6 +352,9 @@ class Engine:
             else:
                 out, self.mgr.cache, stats = fn(self.params, self.mgr.cache,
                                                 toks, pos, nv, key)
+        return out, stats
+
+    def _sync(self, out, stats) -> Tuple[np.ndarray, dict]:
         with span("serve.engine.sync"):
             # the tick's single host sync; the counters come with the output
             out, stats = jax.device_get((out, stats))
@@ -335,9 +396,11 @@ class Engine:
             if self._paged:
                 # the pool is donated into the jit — rebind the returned
                 # buffer or the manager would hold a deleted array
-                _, self.mgr.pool, _ = fn(self.params, self.mgr.pool,
-                                         *self._bt_device(), toks, zero,
-                                         zero, self.key)
+                out, self.mgr.pool, _ = fn(self.params, self.mgr.pool,
+                                           *self._bt_device(), toks, zero,
+                                           zero, self.key)
+                if self._overlap:
+                    self._feed(toks, out)
             else:
                 fn(self.params, self.mgr.cache, toks, zero, zero, self.key)
         if self._paged:
@@ -410,6 +473,7 @@ class Engine:
         host page pool, its device slot is freed (pages actually return to
         the admission budget), and the request re-queues at the head for
         bitwise-identical resumption.  Returns the eviction count."""
+        self._land(self._take_inflight())  # rows and tokens as committed
         active = [(s, r) for s, r in enumerate(self.slot_req)
                   if r is not None]
         n_evict = len(active) - max(int(keep_active), 0)
@@ -486,8 +550,9 @@ class Engine:
         whenever any slot prefills or sits too close to ``max_len`` for the
         fixed verify width (``_row_update`` would clamp the write)."""
         k = self._spec_k
+        fl = self._inflight  # overlap: the tick the device still runs
         active = [(s, r) for s, r in enumerate(self.slot_req)
-                  if r is not None]
+                  if r is not None and not (fl and self._ends_in(fl, s, r))]
         spec = bool(k) and bool(active) and all(
             r.fed >= len(r.prompt)
             and int(self.mgr.pos[s]) + k + 1 <= self.max_len
@@ -495,12 +560,13 @@ class Engine:
         work: List[sched.SlotWork] = []
         for s, req in active:
             P = len(req.prompt)
-            if req.fed < P:  # ragged path only: stream the prompt
-                n = min(self.prefill_chunk, P - req.fed)
+            fed = req.fed + (fl.feeds(s, req) if fl else 0)
+            if fed < P:  # ragged path only: stream the prompt
+                n = min(self.prefill_chunk, P - fed)
                 work.append(sched.SlotWork(
                     s, "prefill",
-                    np.asarray(req.prompt[req.fed:req.fed + n], np.int32),
-                    completes=(req.fed + n == P)))
+                    np.asarray(req.prompt[fed:fed + n], np.int32),
+                    completes=(fed + n == P)))
             elif spec:
                 drafts = self._draft(req, k)
                 toks = np.zeros(k + 1, np.int32)  # fixed width: one bucket
@@ -508,9 +574,10 @@ class Engine:
                 toks[1:1 + len(drafts)] = drafts
                 work.append(sched.SlotWork(
                     s, "decode", toks, n_valid=1 + len(drafts)))
-            else:
+            else:  # -1: the token the tick in flight samples (_feed)
+                tok = -1 if fl and fl.yields(s, req) else req.out[-1]
                 work.append(sched.SlotWork(
-                    s, "decode", np.asarray([req.out[-1]], np.int32)))
+                    s, "decode", np.asarray([tok], np.int32)))
         plan = sched.compose(work, self.mgr.pos, self.B, self.prefill_chunk)
         return plan, spec
 
@@ -561,6 +628,8 @@ class Engine:
     def _tick(self) -> int:
         with span("serve.engine.compose"):
             plan, spec = self._plan()
+        if self._overlap:
+            return self._tick_ahead(plan)
         if plan is None:
             return 0
         if spec:
@@ -570,6 +639,57 @@ class Engine:
         nxt, stats = self._run_fused(self._fused, plan)
         with span("serve.engine.commit", **stats):
             return self._commit(plan, nxt)
+
+    def _tick_ahead(self, plan: Optional[sched.TickPlan]) -> int:
+        """``overlap``: launch the planned tick, then land the one in
+        flight.  Positions advance at launch, so the next plan writes
+        after this tick's entries; prompt progress and tokens are
+        committed when the tick lands."""
+        prev = self._take_inflight()
+        if plan is not None:
+            fill = prev is not None and any(
+                w.kind == "decode" and plan.tokens[w.slot, 0] < 0
+                for w in plan.work)
+            out, stats = self._launch(self._fused, plan,
+                                      prev.out if fill else None)
+            self.mgr.advance([w.slot for w in plan.work],
+                             [len(w.tokens) for w in plan.work])
+            self._inflight = _Flight(
+                plan, {w.slot: self.slot_req[w.slot] for w in plan.work},
+                out, stats)
+        return self._land(prev)
+
+    def _take_inflight(self) -> Optional[_Flight]:
+        fl, self._inflight = self._inflight, None
+        return fl
+
+    def _ends_in(self, fl: _Flight, slot: int, req: Request) -> bool:
+        """True when the tick in flight ends ``req`` by its length limits
+        (an EOS shows only when the tick lands)."""
+        return fl.yields(slot, req) and (
+            len(req.out) + 1 >= req.max_new
+            or self.mgr.pos[slot] >= self.max_len - 1)
+
+    def _land(self, fl: Optional[_Flight]) -> int:
+        """Sync a launched tick and commit it: prompt progress, and each
+        slot's sampled token unless its request ended on the tick before."""
+        if fl is None:
+            return 0
+        nxt, stats = self._sync(fl.out, fl.stats)
+        gen = 0
+        with span("serve.engine.commit", **stats):
+            for w in fl.plan.work:
+                req = fl.reqs[w.slot]
+                if w.kind == "prefill":
+                    req.fed += len(w.tokens)
+                    if not w.completes:
+                        continue
+                if req.done:
+                    continue
+                self._append(req, w.slot, int(nxt[w.slot]),
+                             end=int(fl.plan.pos[w.slot]) + len(w.tokens))
+                gen += 1
+        return gen
 
     def _commit(self, plan: sched.TickPlan, nxt: np.ndarray) -> int:
         """Advance every slot the tick fed and append its sampled token
@@ -632,12 +752,17 @@ class Engine:
         return (self.spec_accepted / self.spec_proposed
                 if self.spec_proposed else 0.0)
 
-    def _append(self, req: Request, slot: int, tok: int):
+    def _append(self, req: Request, slot: int, tok: int,
+                end: Optional[int] = None):
+        """Append a sampled token; ``end`` is the slot's cache length after
+        the tick that sampled it (the manager's, unless positions already
+        advanced for a later tick)."""
         if not req.out:
             req.t_first = time.perf_counter()
         req.out.append(tok)
+        end = self.mgr.pos[slot] if end is None else end
         if (tok == self.eos or len(req.out) >= req.max_new
-                or self.mgr.pos[slot] >= self.max_len - 1):
+                or end >= self.max_len - 1):
             req.done = True
             req.finish_tick = self.ticks
             req.t_done = time.perf_counter()
@@ -655,7 +780,8 @@ class Engine:
         """One scheduler iteration (admit, then one fused tick); True while
         there is still work.  ``run`` loops this; control-plane drivers
         interleave it with ``ControlLoop.step`` ticks."""
-        if not (self.queue or any(r is not None for r in self.slot_req)):
+        if not (self.queue or self._inflight is not None
+                or any(r is not None for r in self.slot_req)):
             return False
         t0 = time.perf_counter()
         with span("serve.engine.admit"):
@@ -676,7 +802,8 @@ class Engine:
             for cb in self.on_tick:
                 cb(smp)
         self.ticks += 1
-        return bool(self.queue or any(r is not None for r in self.slot_req))
+        return bool(self.queue or self._inflight is not None
+                    or any(r is not None for r in self.slot_req))
 
     def run(self, max_ticks: int = 512) -> List[Request]:
         ticks = 0

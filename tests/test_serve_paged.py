@@ -6,7 +6,9 @@ never a numerics change — paged replay is bitwise the contiguous replay
 sequence, and the free-list allocator admits strictly more concurrent
 work than contiguous slots at the same page budget (the churn workload).
 Full-length GQA and MLA stacks keep their entries in the page pool
-(``TestInPoolStep``); ring caches keep the gather/scatter step.
+(``TestInPoolStep``); ring caches keep the gather/scatter step.  With
+``overlap=True`` the in-pool engine launches each tick before it lands the
+one before and serves the same tokens (``TestOverlap``).
 """
 import functools
 import hashlib
@@ -388,8 +390,9 @@ WAVES = [(0, 0, 12, 9), (0, 1, 5, 14), (2, 2, 11, 6), (5, 3, 3, 10),
 def _serve_waves(model, params, cfg, on_fused=None, **kw):
     """Serve WAVES through an engine; ``on_fused(eng, run, fn, plan)``
     wraps every fused tick.  Returns the engine and the tokens by rid."""
-    eng = Engine(model, params, batch_slots=3, max_len=64, page_size=8,
-                 prefill_chunk=8, eos_id=-1, warmup=False, **kw)
+    opts = dict(batch_slots=3, max_len=64, page_size=8, prefill_chunk=8,
+                eos_id=-1, warmup=False)
+    eng = Engine(model, params, **{**opts, **kw})
     if on_fused is not None:
         run = eng._run_fused
         eng._run_fused = lambda fn, plan: on_fused(eng, run, fn, plan)
@@ -579,3 +582,83 @@ class TestGqaStepPinned:
         for name in ("k", "v", "pos_ids"):
             h.update(np.asarray(eng.mgr.pool["stack"][name]).tobytes())
         assert h.hexdigest() == self.POOL
+
+
+class TestOverlap:
+    """``overlap=True``: each step launches tick n, then syncs and commits
+    tick n-1, whose sampled tokens feed tick n's decode rows on the
+    device.  The served tokens are the synchronous engine's."""
+
+    @staticmethod
+    def _eos(ref):
+        """The token the reference serves most often: with it as EOS some
+        requests end early, one tick before the host can see it."""
+        toks = [t for out in ref.values() for t in out]
+        return max(sorted(set(toks)), key=toks.count)
+
+    @pytest.mark.parametrize("eos", [False, True])
+    @pytest.mark.parametrize("arch", ["dense", "mla"])
+    def test_tokens_match_the_synchronous_engine(self, request, arch, eos):
+        cfg, model, params = request.getfixturevalue(arch)
+        _, ref = _serve_waves(model, params, cfg, paged=True)
+        kw = {"eos_id": self._eos(ref)} if eos else {}
+        if eos:
+            _, ref = _serve_waves(model, params, cfg, paged=True, **kw)
+            assert any(len(out) < m for (_, rid, _, m) in WAVES
+                       for r, out in ref.items() if r == rid)
+        eng, got = _serve_waves(model, params, cfg, paged=True, overlap=True,
+                                **kw)
+        assert got == ref
+        assert eng.kv_pool_ticks > 0 and eng._inflight is None
+        assert eng.mgr.pages_in_use == eng.mgr.recount_pages() == 0
+
+    def test_launches_before_it_lands(self, dense):
+        """Every tick but the first is launched before the one before it
+        is synced; the last lands on a step that launches nothing."""
+        cfg, model, params = dense
+        seq = []
+        eng = Engine(model, params, batch_slots=2, max_len=64, page_size=8,
+                     prefill_chunk=8, paged=True, overlap=True, eos_id=-1,
+                     warmup=False)
+        launch, sync = eng._launch, eng._sync
+        eng._launch = lambda *a: (seq.append("L"), launch(*a))[1]
+        eng._sync = lambda *a: (seq.append("S"), sync(*a))[1]
+        for rid in range(2):
+            eng.submit(Request(rid, _prompt(cfg, rid), max_new=4))
+        steps = 0
+        while eng.step():
+            steps += 1
+        assert "".join(seq) == "L" + "LS" * (len(seq) // 2 - 1) + "S"
+        # the step that lands the last tick reports no work left
+        assert steps == seq.count("L")
+        assert [len(r.out) for r in eng.finished] == [4, 4]
+
+    def test_preemption_lands_the_tick_in_flight(self, dense):
+        """An eviction commits the tick in flight first, so the request
+        parks the rows and tokens of every launched tick and resumes to
+        the tokens it would have served."""
+        cfg, model, params = dense
+        _, ref = _outs(cfg, model, params, n_req=2, max_new=12, paged=True)
+        eng = Engine(model, params, batch_slots=2, max_len=64, paged=True,
+                     overlap=True, eos_id=-1, warmup=False)
+        for rid in range(2):
+            eng.submit(Request(rid, _prompt(cfg, rid), max_new=12))
+        for _ in range(5):
+            eng.step()
+        assert eng._inflight is not None
+        assert eng.preempt_to(1) == 1
+        assert eng._inflight is None and eng.pool.pages_held > 0
+        eng.run()
+        assert {r.rid: tuple(r.out) for r in eng.finished} == ref
+        assert eng.preempts == 1 and eng.pool.pages_held == 0
+
+    def test_requires_the_in_pool_step(self, dense, swa):
+        cfg, model, params = dense
+        for kw in ({"paged": False}, {"paged": True, "speculate": 2}):
+            with pytest.raises(ValueError, match="overlap"):
+                Engine(model, params, batch_slots=2, max_len=64,
+                       overlap=True, warmup=False, **kw)
+        _, swa_model, swa_params = swa
+        with pytest.raises(ValueError, match="overlap"):
+            Engine(swa_model, swa_params, batch_slots=2, max_len=64,
+                   paged=True, overlap=True, warmup=False)

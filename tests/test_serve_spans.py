@@ -223,6 +223,31 @@ def test_commit_spans_carry_the_expert_counters(mla, tmp_path):
                for c in commits)
 
 
+def test_overlapped_ticks_land_in_the_next_step(mla, tmp_path):
+    """``overlap``: a step dispatches its tick before it syncs and commits
+    the one before, so the first step holds no sync, the last no dispatch,
+    and every step keeps the phases' order; the tokens are the
+    synchronous engine's, and the commit spans' counters (the landed
+    tick's) still add up to the engine's totals."""
+    cfg = mla[0]
+    plain, _ = _serve(_engine(mla), _requests(cfg))
+    eng = _engine(mla, overlap=True)
+    got, tr = _serve(eng, _requests(cfg), str(tmp_path))
+    assert got == plain
+    spans = [s for s in tr.spans if s.name.startswith("serve.engine.")
+             and s.name != "serve.engine.release"]
+    phases = [[s.name.rsplit(".", 1)[1] for s in spans
+               if st.start <= s.start and s.end <= st.end]
+              for st in tr.spans_named("serve.step")]
+    launch, land = PHASES[:4], PHASES[:2] + PHASES[4:]
+    assert phases[0] == launch and phases[-1] == land
+    assert all(p in (PHASES, land) for p in phases[1:-1])
+    assert phases.count(PHASES) >= len(phases) - 4
+    commits = tr.spans_named("serve.engine.commit")
+    assert sum(c.stats["routed_local"] for c in commits) == \
+        eng.routed_local > 0
+
+
 def test_dense_commit_spans_carry_no_counters(dense, tmp_path):
     eng = _engine(dense)
     _, tr = _serve(eng, _requests(dense[0]), str(tmp_path))
