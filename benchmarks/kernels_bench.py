@@ -208,7 +208,9 @@ def closed_loop(quick: bool = True) -> Dict:
     # vs the contiguous cache, interleaved best-of-reps so machine drift
     # hits both paths equally.  The 1.2x bound is the PR's acceptance gate.
     def _steady(paged):
-        e = Engine(model, params, batch_slots=4, max_len=64, paged=paged)
+        # the synchronous step: a tick composed and timed on its own
+        e = Engine(model, params, batch_slots=4, max_len=64, paged=paged,
+                   overlap=False)
         for rid in range(4):
             e.submit(Request(rid, np.arange(6) % cfg.vocab_size,
                              max_new=60))

@@ -47,15 +47,21 @@ Two scheduling paths, picked by model family:
   exact-length prefill per request and scatters the row; decode then joins
   the same fused step.
 
-``overlap=True`` (the in-pool path, without speculation) runs one tick
-ahead of the host: each ``step()`` launches tick n, then syncs and commits
-tick n-1, whose sampled tokens feed tick n's decode rows on the device.
-Composing, uploading and dispatching a tick then happen while the device
-still runs the one before, so they drop out of the gap between tokens.  A
-request's tokens land one step after its tick is launched; the host cannot
-see an EOS before the next tick is launched, so a slot whose request ended
-on EOS runs one tick more, whose token is dropped.  Preemption and
-``drain`` land the tick in flight first.
+Overlap: wherever it can (the in-pool path, without speculation) the
+engine runs one tick ahead of the host by default: each ``step()``
+launches tick n, then syncs and commits tick n-1, whose sampled tokens
+feed tick n's decode rows on the device.  Composing, uploading and
+dispatching a tick then happen while the device still runs the one
+before, so they drop out of the gap between tokens.  A request's tokens
+land one step after its tick is launched; when every request the engine
+holds ends in the tick just launched, nothing can follow it, so the same
+step lands it too and no tick stays in flight while the engine is empty.
+The cost of the default: the host cannot see an EOS before the next tick
+is launched, so a slot whose request ended on EOS runs one tick more,
+whose token is dropped.  Preemption and ``drain`` land the tick in flight
+first.  Ring caches, recurrent stacks, the gather path and speculation
+keep the synchronous step; ``overlap=False`` asks for it anywhere, and
+``overlap=True`` where the overlapped step cannot run raises.
 
 Control-plane hooks (repro.control, DESIGN.md §3): EVERY ``step()`` emits a
 ``TickSample`` — including admit-only and fully-throttled iterations, so
@@ -67,7 +73,9 @@ so they land on the same clock as the device's operations:
 ``serve.engine.admit``, ``.compose`` (plan, page reservation, preemption),
 ``.upload`` (key split, tick arrays, block table; stat ``bt_sent``),
 ``.dispatch`` (the fused call; stats ``width``, ``prefill``, ``decode``,
-``prompt_tokens``, ``kv_pool``: 1 on the in-pool path), ``.sync`` (the
+``prompt_tokens``, ``kv_pool``: 1 on the in-pool path, ``ahead``: 1 when
+launched while another tick was in flight, counted in
+``Engine.ticks_ahead``), ``.sync`` (the
 host copy of the output), ``.commit`` (advance and append; on the
 in-pool path of an expert model, stats ``routed_local`` and
 ``experts_touched``, the tick's expert counters, also totalled on
@@ -155,7 +163,7 @@ class Engine:
                  top_k: int = 0, prefill_chunk: int = 16,
                  page_size: int = 16, expandable: bool = False,
                  paged: bool = False, total_pages: Optional[int] = None,
-                 speculate: int = 0, overlap: bool = False,
+                 speculate: int = 0, overlap: Optional[bool] = None,
                  seed: int = 0, warmup: bool = True,
                  pool: Optional[HostPagePool] = None):
         self.model = model
@@ -240,12 +248,14 @@ class Engine:
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         self._kv_pool = self._paged and model.decodes_in_pool
-        if overlap and (not self._kv_pool or self._spec_k):
+        can_overlap = self._kv_pool and not self._spec_k
+        if overlap and not can_overlap:
             raise ValueError(
                 "overlap requires the in-pool paged step (paged=True on a "
                 "full-length attention stack) and no speculation")
-        self._overlap = bool(overlap)
+        self._overlap = can_overlap if overlap is None else bool(overlap)
         self._inflight: Optional[_Flight] = None
+        self.ticks_ahead = 0  # ticks launched while another was in flight
         self.kv_pool_ticks = 0  # fused ticks run on the in-pool path
         # the in-pool step's expert counters, summed over layers and ticks:
         # picks that landed on experts this chip holds, and held experts
@@ -326,25 +336,28 @@ class Engine:
         tick's expert counters (empty where the step has none)."""
         return self._sync(*self._launch(fn, plan))
 
-    def _launch(self, fn, plan: sched.TickPlan, prev=None):
+    def _launch(self, fn, plan: sched.TickPlan,
+                prev: Optional[_Flight] = None):
         """Upload the tick's arrays and dispatch the fused step; returns its
-        device output and counters.  ``prev``, the sampled tokens of the
-        tick in flight, fills the plan's -1 decode inputs on the device."""
+        device output and counters.  ``prev`` is the tick in flight: its
+        sampled tokens fill the plan's -1 decode inputs on the device."""
         with span("serve.engine.upload",
                   bt_sent=int(self._paged and self._bt_stale())):
             self.key, key = jax.random.split(self.key)
             toks = jnp.asarray(plan.tokens)
-            if prev is not None:
-                toks = self._feed(toks, prev)
+            if prev is not None and (plan.tokens < 0).any():
+                toks = self._feed(toks, prev.out)
             pos = jnp.asarray(plan.pos)
             nv = jnp.asarray(plan.n_valid)
             if self._paged:
                 tables = self._bt_device()
         prefill = [w for w in plan.work if w.kind == "prefill"]
+        ahead = int(prev is not None)
+        self.ticks_ahead += ahead
         with span("serve.engine.dispatch", width=plan.width,
                   prefill=len(prefill), decode=len(plan.work) - len(prefill),
                   prompt_tokens=sum(len(w.tokens) for w in prefill),
-                  kv_pool=int(self._kv_pool)):
+                  kv_pool=int(self._kv_pool), ahead=ahead):
             if self._paged:
                 out, self.mgr.pool, stats = fn(self.params, self.mgr.pool,
                                                *tables, toks, pos, nv, key)
@@ -644,20 +657,23 @@ class Engine:
         """``overlap``: launch the planned tick, then land the one in
         flight.  Positions advance at launch, so the next plan writes
         after this tick's entries; prompt progress and tokens are
-        committed when the tick lands."""
+        committed when the tick lands.  A launched tick that every held
+        request ends in lands at once: no tick could follow it."""
         prev = self._take_inflight()
         if plan is not None:
-            fill = prev is not None and any(
-                w.kind == "decode" and plan.tokens[w.slot, 0] < 0
-                for w in plan.work)
-            out, stats = self._launch(self._fused, plan,
-                                      prev.out if fill else None)
+            out, stats = self._launch(self._fused, plan, prev)
             self.mgr.advance([w.slot for w in plan.work],
                              [len(w.tokens) for w in plan.work])
             self._inflight = _Flight(
                 plan, {w.slot: self.slot_req[w.slot] for w in plan.work},
                 out, stats)
-        return self._land(prev)
+        gen = self._land(prev)
+        fl = self._inflight
+        if fl is not None and all(self._ends_in(fl, s, r)
+                                  for s, r in enumerate(self.slot_req)
+                                  if r is not None):
+            gen += self._land(self._take_inflight())
+        return gen
 
     def _take_inflight(self) -> Optional[_Flight]:
         fl, self._inflight = self._inflight, None

@@ -187,7 +187,7 @@ class TestLatentPool:
         cfg, model, params = mla
         eng = Engine(model, params, batch_slots=2, max_len=64,
                      prefill_chunk=16, page_size=16, paged=True, eos_id=-1,
-                     warmup=False)
+                     overlap=False, warmup=False)
         a = Request(0, (np.arange(40) * 3 % cfg.vocab_size).astype(np.int32),
                     max_new=20)
         eng.submit(a)

@@ -8,7 +8,8 @@ work than contiguous slots at the same page budget (the churn workload).
 Full-length GQA and MLA stacks keep their entries in the page pool
 (``TestInPoolStep``); ring caches keep the gather/scatter step.  With
 ``overlap=True`` the in-pool engine launches each tick before it lands the
-one before and serves the same tokens (``TestOverlap``).
+one before and serves the same tokens (``TestOverlap``); left unset, it
+does so wherever that step runs (``TestOverlapDefault``).
 """
 import functools
 import hashlib
@@ -424,7 +425,7 @@ class TestInPoolStep:
             return run(fn, plan)
 
         eng, got = _serve_waves(model, params, cfg, on_fused=spy, paged=True,
-                                speculate=speculate)
+                                speculate=speculate, overlap=False)
         assert got == ref, "the in-pool step changed the tokens"
         assert eng.kv_pool_ticks == len(widths) > 0
         chunk = [nv for w, nv in widths if w == 8]
@@ -470,7 +471,8 @@ class TestInPoolStep:
             checked.append(plan.width)
             return out
 
-        _serve_waves(model, params, cfg, on_fused=check, paged=True)
+        _serve_waves(model, params, cfg, on_fused=check, paged=True,
+                     overlap=False)
         assert 1 in checked and 8 in checked
 
     def test_write_near_max_len_keeps_live_entries(self, dense):
@@ -484,7 +486,7 @@ class TestInPoolStep:
         cfg, model, params = dense
         eng = Engine(model, params, batch_slots=2, max_len=64,
                      prefill_chunk=16, page_size=16, paged=True, eos_id=-1,
-                     warmup=False)
+                     overlap=False, warmup=False)
         a = Request(0, _prompt(cfg, 0, 40), max_new=20)
         eng.submit(a)
         slot = None
@@ -575,7 +577,8 @@ class TestGqaStepPinned:
 
     def test_served_tokens_and_pool_bitwise(self, qwen3):
         cfg, model, params = qwen3
-        eng, got = _serve_waves(model, params, cfg, paged=True)
+        eng, got = _serve_waves(model, params, cfg, paged=True,
+                                overlap=False)
         assert got == self.TOKENS
         assert eng.kv_pool_ticks == eng.ticks and eng.routed_local == 0
         h = hashlib.sha256()
@@ -600,10 +603,11 @@ class TestOverlap:
     @pytest.mark.parametrize("arch", ["dense", "mla"])
     def test_tokens_match_the_synchronous_engine(self, request, arch, eos):
         cfg, model, params = request.getfixturevalue(arch)
-        _, ref = _serve_waves(model, params, cfg, paged=True)
+        _, ref = _serve_waves(model, params, cfg, paged=True, overlap=False)
         kw = {"eos_id": self._eos(ref)} if eos else {}
         if eos:
-            _, ref = _serve_waves(model, params, cfg, paged=True, **kw)
+            _, ref = _serve_waves(model, params, cfg, paged=True,
+                                  overlap=False, **kw)
             assert any(len(out) < m for (_, rid, _, m) in WAVES
                        for r, out in ref.items() if r == rid)
         eng, got = _serve_waves(model, params, cfg, paged=True, overlap=True,
@@ -614,7 +618,8 @@ class TestOverlap:
 
     def test_launches_before_it_lands(self, dense):
         """Every tick but the first is launched before the one before it
-        is synced; the last lands on a step that launches nothing."""
+        is synced; the last, which every request ends in, lands in the
+        step that launches it, since no tick can follow it."""
         cfg, model, params = dense
         seq = []
         eng = Engine(model, params, batch_slots=2, max_len=64, page_size=8,
@@ -629,8 +634,10 @@ class TestOverlap:
         while eng.step():
             steps += 1
         assert "".join(seq) == "L" + "LS" * (len(seq) // 2 - 1) + "S"
-        # the step that lands the last tick reports no work left
-        assert steps == seq.count("L")
+        # the step that launches and lands the last tick reports no work
+        # left: one step per launch, and nothing in flight after it
+        assert steps + 1 == seq.count("L") == eng.ticks
+        assert eng._inflight is None
         assert [len(r.out) for r in eng.finished] == [4, 4]
 
     def test_preemption_lands_the_tick_in_flight(self, dense):
@@ -638,7 +645,8 @@ class TestOverlap:
         parks the rows and tokens of every launched tick and resumes to
         the tokens it would have served."""
         cfg, model, params = dense
-        _, ref = _outs(cfg, model, params, n_req=2, max_new=12, paged=True)
+        _, ref = _outs(cfg, model, params, n_req=2, max_new=12, paged=True,
+                       overlap=False)
         eng = Engine(model, params, batch_slots=2, max_len=64, paged=True,
                      overlap=True, eos_id=-1, warmup=False)
         for rid in range(2):
@@ -662,3 +670,100 @@ class TestOverlap:
         with pytest.raises(ValueError, match="overlap"):
             Engine(swa_model, swa_params, batch_slots=2, max_len=64,
                    paged=True, overlap=True, warmup=False)
+
+
+class TestOverlapDefault:
+    """Left unset, ``overlap`` is on wherever the overlapped step runs (the
+    in-pool paged step without speculation) and off everywhere else,
+    without raising."""
+
+    @pytest.mark.parametrize("arch, kw, ahead", [
+        ("dense", {"paged": True}, True),
+        ("mla", {"paged": True}, True),
+        ("dense", {"paged": True, "speculate": 3}, False),
+        ("swa", {"paged": True}, False),
+        ("dense", {"paged": False}, False),
+    ])
+    def test_overlaps_where_the_in_pool_step_runs(self, request, arch, kw,
+                                                  ahead):
+        cfg, model, params = request.getfixturevalue(arch)
+        _, ref = _serve_waves(model, params, cfg, overlap=False, **kw)
+        eng, got = _serve_waves(model, params, cfg, **kw)
+        assert got == ref
+        assert (eng.ticks_ahead > 0) == ahead and eng._inflight is None
+        if not ahead:  # asked for where it cannot run, it still raises
+            with pytest.raises(ValueError, match="overlap"):
+                Engine(model, params, batch_slots=3, max_len=64,
+                       overlap=True, warmup=False, **kw)
+
+    def test_open_loop_arrivals(self, dense):
+        """Requests offered between steps as an open-loop server offers
+        them: one while a tick is in flight, which the next step admits
+        and launches, and two after the engine has emptied and idled.
+        Nothing stays in flight while the engine is empty, a first token
+        is stamped only once the prompt's last chunk has landed, after its
+        submission, and the tokens are the synchronous engine's."""
+        cfg, model, params = dense
+        # (step, rid, prompt length, max_new): the engine is empty by step 30
+        arrivals = [(0, 0, 12, 6), (1, 1, 5, 9), (3, 2, 11, 4),
+                    (30, 3, 3, 5), (31, 4, 9, 3)]
+
+        def serve(overlap):
+            eng = Engine(model, params, batch_slots=3, max_len=64,
+                         page_size=8, prefill_chunk=8, paged=True, eos_id=-1,
+                         overlap=overlap, warmup=False)
+            due, reqs, idle, ahead, offered = list(arrivals), [], [], 0, 0
+            for step in range(40):
+                mid_flight = []
+                while due and due[0][0] <= step:
+                    _, rid, n, m = due.pop(0)
+                    reqs.append(Request(rid, _prompt(cfg, rid, n), max_new=m))
+                    eng.submit(reqs[-1])
+                    if eng._inflight is not None:
+                        mid_flight.append(reqs[-1])
+                if all(r.done for r in reqs):  # waiting for arrivals
+                    idle.append(eng._inflight)
+                    continue
+                launched, was = eng.kv_pool_ticks, eng._inflight is not None
+                eng.step()
+                ahead += was and eng.kv_pool_ticks > launched
+                offered += len(mid_flight)
+                for r in mid_flight:  # admitted and launched at once
+                    assert r.t_admit is not None
+                    assert r in eng._inflight.reqs.values()
+                for r in reqs:
+                    assert not r.out or r.fed == len(r.prompt)
+            assert not due and all(r.done for r in reqs)
+            assert eng._inflight is None and idle
+            assert all(fl is None for fl in idle)
+            assert all(r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+                       for r in reqs)
+            assert eng.ticks_ahead == ahead
+            assert (offered > 0) == (overlap is None)
+            return eng, {r.rid: tuple(r.out) for r in reqs}
+
+        _, ref = serve(False)
+        eng, got = serve(None)
+        assert got == ref
+        assert 0 < eng.ticks_ahead < eng.kv_pool_ticks
+
+    def test_an_empty_plan_lands_the_tick_in_flight(self, dense):
+        """A step that plans nothing still syncs and commits the tick in
+        flight."""
+        cfg, model, params = dense
+        eng = Engine(model, params, batch_slots=2, max_len=64, paged=True,
+                     eos_id=-1, warmup=False)
+        req = Request(0, _prompt(cfg, 0), max_new=6)
+        eng.submit(req)
+        eng.step()
+        eng.step()
+        assert eng._inflight is not None and len(req.out) == 1
+        plan = eng._plan
+        eng._plan = lambda: (None, False)
+        eng.step()
+        assert eng._inflight is None and len(req.out) == 2
+        eng._plan = plan
+        eng.run()
+        _, ref = _outs(cfg, model, params, n_req=1, max_new=6, paged=True,
+                       overlap=False)
+        assert tuple(req.out) == ref[0]

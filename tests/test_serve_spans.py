@@ -84,7 +84,7 @@ def _reader(name):
 def test_every_tick_holds_the_phases_in_order(dense, tmp_path):
     cfg = dense[0]
     reqs = _requests(cfg)
-    _, tr = _serve(_engine(dense), reqs, str(tmp_path))
+    _, tr = _serve(_engine(dense, overlap=False), reqs, str(tmp_path))
     steps = tr.spans_named("serve.step")
     spans = [s for s in tr.spans if s.name.startswith("serve.engine.")]
     assert steps and len(spans) >= len(PHASES) * len(steps)
@@ -225,12 +225,13 @@ def test_commit_spans_carry_the_expert_counters(mla, tmp_path):
 
 def test_overlapped_ticks_land_in_the_next_step(mla, tmp_path):
     """``overlap``: a step dispatches its tick before it syncs and commits
-    the one before, so the first step holds no sync, the last no dispatch,
-    and every step keeps the phases' order; the tokens are the
-    synchronous engine's, and the commit spans' counters (the landed
-    tick's) still add up to the engine's totals."""
+    the one before, so the first step holds no sync, the last (which every
+    request ends in) lands the tick before and its own, and every step
+    keeps the phases' order; the tokens are the synchronous engine's, and
+    the commit spans' counters (the landed tick's) still add up to the
+    engine's totals."""
     cfg = mla[0]
-    plain, _ = _serve(_engine(mla), _requests(cfg))
+    plain, _ = _serve(_engine(mla, overlap=False), _requests(cfg))
     eng = _engine(mla, overlap=True)
     got, tr = _serve(eng, _requests(cfg), str(tmp_path))
     assert got == plain
@@ -240,9 +241,13 @@ def test_overlapped_ticks_land_in_the_next_step(mla, tmp_path):
                if st.start <= s.start and s.end <= st.end]
               for st in tr.spans_named("serve.step")]
     launch, land = PHASES[:4], PHASES[:2] + PHASES[4:]
-    assert phases[0] == launch and phases[-1] == land
+    assert phases[0] == launch and phases[-1] == PHASES + land[2:]
     assert all(p in (PHASES, land) for p in phases[1:-1])
     assert phases.count(PHASES) >= len(phases) - 4
+    # every tick but the first is launched while another is in flight
+    ahead = [d.stats["ahead"] for d in tr.spans_named("serve.engine.dispatch")]
+    assert ahead == [0] + [1] * (len(ahead) - 1)
+    assert eng.ticks_ahead == len(ahead) - 1 > 0
     commits = tr.spans_named("serve.engine.commit")
     assert sum(c.stats["routed_local"] for c in commits) == \
         eng.routed_local > 0
