@@ -111,7 +111,7 @@ from repro.serve.step import sample
 
 @dataclass
 class _Flight:
-    """A tick launched and not yet landed (``overlap=True``): its plan, the
+    """A tick launched and not yet landed (overlap): its plan, the
     request each slot served, and the device's sampled tokens and
     counters."""
     plan: sched.TickPlan
