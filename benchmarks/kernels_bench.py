@@ -204,40 +204,6 @@ def closed_loop(quick: bool = True) -> Dict:
     eng_c = Engine(model, params, batch_slots=4, max_len=64)
     out["serve_tokens_per_s_contiguous"] = _serve_day(eng_c)
 
-    # paged decode tax: one fused decode tick, block-table gather/scatter
-    # vs the contiguous cache, interleaved best-of-reps so machine drift
-    # hits both paths equally.  The 1.2x bound is the PR's acceptance gate.
-    def _steady(paged):
-        # the synchronous step: a tick composed and timed on its own
-        e = Engine(model, params, batch_slots=4, max_len=64, paged=paged,
-                   overlap=False)
-        for rid in range(4):
-            e.submit(Request(rid, np.arange(6) % cfg.vocab_size,
-                             max_new=60))
-        for _ in range(4):
-            e.step()  # feed prompts; all slots now mid-decode
-        plan, _ = e._compose()
-        e._run_fused(e._fused, plan)
-        return e, plan
-
-    pair = {False: _steady(False), True: _steady(True)}
-    best = {False: float("inf"), True: float("inf")}
-    iters = 20
-    for _ in range(9):
-        for paged, (e, plan) in pair.items():
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                e._run_fused(e._fused, plan)
-            best[paged] = min(best[paged],
-                              (time.perf_counter() - t0) / iters)
-    out["contig_decode_us"] = best[False] * 1e6
-    out["paged_decode_us"] = best[True] * 1e6
-    tax = out["paged_decode_us"] / out["contig_decode_us"]
-    assert tax <= 1.2, (
-        f"paged decode tax {tax:.3f}x exceeds the 1.2x budget "
-        f"({out['paged_decode_us']:.0f}us vs "
-        f"{out['contig_decode_us']:.0f}us)")
-
     # -- control-plane latencies --------------------------------------------
     from repro.control.lut import sweep_points
     prof = TF.StepProfile.from_roofline(compute_s=0.7, memory_s=0.4,

@@ -6,15 +6,12 @@ slots/pages/positions (``PagedKVCacheManager`` makes pages real: free-list
 ``repro.control.AdmissionController`` co-schedules admission with the rail
 plan, priced off actual free pages.
 """
-from repro.serve.cache import (ExpandableKVCacheManager,
-                               ExpandablePagedKVCacheManager, HostPagePool,
-                               KVCacheManager, PageAllocator,
+from repro.serve.cache import (HostPagePool, KVCacheManager, PageAllocator,
                                PagedKVCacheManager)
 from repro.serve.engine import Engine, Request
 from repro.serve.scheduler import SlotWork, TickPlan, compose
 from repro.serve.step import sample
 
-__all__ = ["Engine", "Request", "KVCacheManager", "ExpandableKVCacheManager",
-           "PagedKVCacheManager", "ExpandablePagedKVCacheManager",
+__all__ = ["Engine", "Request", "KVCacheManager", "PagedKVCacheManager",
            "PageAllocator", "HostPagePool",
            "SlotWork", "TickPlan", "compose", "sample"]

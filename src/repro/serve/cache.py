@@ -22,24 +22,27 @@ all per-slot bookkeeping the scheduler needs:
   the front — no shape-matching heuristics (which break when a layer count
   equals the slot count).
 
-``ExpandableKVCacheManager`` (modeled on foundation-model-stack's
-ExpandableKVCacheManager) starts with a small sequence capacity and doubles
-it on demand up to ``max_len``: sequence axes are probed the same way, new
-space is zero-filled except ``pos_ids`` (filled with -1 = invalid).
-
 ``PagedKVCacheManager`` makes pages *real* (vLLM-style): the device cache is
 a pool of ``total_pages`` physical pages (pages carried on the probed batch
 axis, ``page_size`` tokens on the probed sequence axis) plus one permanently
 invalid **null page**; each slot owns a block table mapping logical page
 index -> physical page, filled from a free-list :class:`PageAllocator` at
 ``page_size`` granularity.  Layout is non-contiguous by construction — any
-free page serves any slot, so admission never fails on fragmentation.  The
-engine's fused step gathers a slot-contiguous logical cache through the
-block tables, runs the *unchanged* ``Model.decode``, and scatters the pages
-back — identical ops on identical visible values, so outputs stay bitwise
-identical to the contiguous manager.  Freed/trimmed pages get their
-``pos_ids`` invalidated before returning to the pool so a recycled page can
-never leak stale entries through another slot's attention mask.
+free page serves any slot, so admission never fails on fragmentation.
+Freed/trimmed pages get their ``pos_ids`` invalidated before returning to
+the pool so a recycled page can never leak stale entries through another
+slot's attention mask.
+
+Each manager owns how its storage meets the model: ``decode_step`` is the
+traceable model call the engine fuses into its one jitted step, over the
+state (``cache``, donated where ``donates``) and the device ``tables()``.
+The contiguous manager runs ``Model.decode`` on its cache.  The paged one
+runs ``Model.decode_paged`` in the pool where the stack holds full-length
+attention entries (``kv_pool``), and otherwise (ring caches) gathers a
+slot-contiguous logical cache through the block tables, runs the
+*unchanged* ``Model.decode`` and scatters the pages back — identical ops
+on identical visible values, so outputs stay bitwise identical to the
+contiguous manager.
 """
 from __future__ import annotations
 
@@ -78,8 +81,11 @@ def _is_pos_ids(path) -> bool:
 class KVCacheManager:
     """Fixed-capacity paged cache over ``slots`` rows of length ``max_len``."""
 
+    kv_pool = False  # the model steps the slot-contiguous cache
+    donates = False
+
     def __init__(self, model, slots: int, max_len: int,
-                 page_size: int = 16, alloc: bool = True):
+                 page_size: int = 16):
         self.model = model
         self.slots = slots
         self.max_len = max_len
@@ -88,8 +94,7 @@ class KVCacheManager:
             model,
             lambda: model.cache(slots, max_len, abstract=True),
             lambda: model.cache(slots + 1, max_len, abstract=True))
-        if alloc:
-            self.cache = model.cache(slots, max_len)
+        self.cache = model.cache(slots, max_len)
         # host-side bookkeeping (no device sync needed to schedule)
         self.pos = np.zeros(slots, np.int32)        # next decode position
         self.lengths = np.zeros(slots, np.int32)    # prompt length
@@ -136,6 +141,24 @@ class KVCacheManager:
         self._scatter = jax.jit(_scatter)
         self._invalidate = jax.jit(_invalidate)
         self._gather = jax.jit(_gather)
+
+    # -- the fused step's model call ------------------------------------------
+    def decode_step(self, params, cache, tables, tokens, pos, n_valid):
+        """``Model.decode`` on the cache (traceable; no tables)."""
+        with jax.named_scope("decode"):
+            logits, cache = self.model.decode(params, tokens, cache, pos,
+                                              n_valid=n_valid)
+        return logits, cache, {}
+
+    def tables_stale(self) -> bool:
+        return False
+
+    def tables(self) -> tuple:
+        return ()
+
+    def warm_release(self):
+        """Compile the release path's invalidation ahead of traffic."""
+        self._invalidate(self.cache, jnp.asarray([0]))
 
     # -- slot lifecycle -------------------------------------------------------
     @property
@@ -215,25 +238,7 @@ class KVCacheManager:
 
     def restore(self, slot: int, rows, pos: int):
         """Scatter one preempted row set back into a (re)allocated slot and
-        rewind its decode position — the resume half of preemption.  Rows
-        captured before an :class:`ExpandableKVCacheManager` growth are
-        padded out to the current leaf shapes (fill -1 for ``pos_ids``)."""
-
-        def fit(path, ax, row, cur):
-            widths, need = [], False
-            for i, (r, c) in enumerate(zip(row.shape, cur.shape)):
-                if i == ax:
-                    widths.append((0, 0))
-                else:
-                    widths.append((0, max(c - r, 0)))
-                    need = need or c > r
-            if not need:
-                return row
-            fill = -1 if _is_pos_ids(path) else 0
-            return jnp.pad(jnp.asarray(row), widths, constant_values=fill)
-
-        rows = jax.tree_util.tree_map_with_path(
-            fit, self.batch_axes, rows, self.cache)
+        rewind its decode position — the resume half of preemption."""
         self.write_rows([slot], rows)
         self.pos[slot] = int(pos)
         self._set_slot_pages(
@@ -338,55 +343,6 @@ class HostPagePool:
         return len(self._rows)
 
 
-class ExpandableKVCacheManager(KVCacheManager):
-    """Starts at ``initial_len`` sequence capacity, doubles up to ``max_len``.
-
-    Growth re-allocates only the leaves that actually carry a sequence axis
-    (probed structurally — SSM states and window-clamped ring buffers are
-    left alone), zero-padding K/V and padding ``pos_ids`` with -1.
-    """
-
-    def __init__(self, model, slots: int, max_len: int,
-                 initial_len: int = 64, page_size: int = 16):
-        initial_len = min(initial_len, max_len)
-        super().__init__(model, slots, max_len, page_size, alloc=False)
-        self.capacity = initial_len
-        self.cache = model.cache(slots, initial_len)
-        self.grows = 0
-
-    def _seq_axes(self, old_len: int, new_len: int):
-        return _probe_axes(
-            self.model,
-            lambda: self.model.cache(self.slots, old_len, abstract=True),
-            lambda: self.model.cache(self.slots, new_len, abstract=True))
-
-    def ensure(self, needed: int):
-        """Grow capacity (doubling) until >= needed tokens per slot."""
-        if needed <= self.capacity:
-            return
-        new_cap = self.capacity
-        while new_cap < needed:
-            new_cap = min(new_cap * 2, self.max_len)
-            if new_cap == self.capacity:
-                raise ValueError(
-                    f"request needs {needed} tokens; max_len={self.max_len}")
-        seq_axes = self._seq_axes(self.capacity, new_cap)
-
-        def grow(path, ax, leaf):
-            if ax == NO_AXIS:
-                return leaf
-            pad = new_cap - leaf.shape[ax]
-            widths = [(0, 0)] * leaf.ndim
-            widths[ax] = (0, pad)
-            fill = -1 if _is_pos_ids(path) else 0
-            return jnp.pad(leaf, widths, constant_values=fill)
-
-        self.cache = jax.tree_util.tree_map_with_path(
-            grow, seq_axes, self.cache)
-        self.capacity = new_cap
-        self.grows += 1
-
-
 # =============================================================================
 # true paged attention: free-list allocator + block-table managers
 # =============================================================================
@@ -446,14 +402,16 @@ class PagedKVCacheManager:
 
     ``gather_logical``/``scatter_logical`` convert between the pool and the
     slot-contiguous logical layout ``Model.decode`` expects; they are plain
-    traceable functions so the engine can fuse gather -> decode -> scatter
-    into one jitted step for layouts that ``Model.decode_paged`` (which
-    works on the pool itself) does not take.  Because the gathered logical
-    cache is bitwise equal to the contiguous manager's cache at every
+    traceable functions so :meth:`decode_step` fuses gather -> decode ->
+    scatter into one jitted step for layouts that ``Model.decode_paged``
+    (which works on the pool itself) does not take.  Because the gathered
+    logical cache is bitwise equal to the contiguous manager's cache at every
     mask-visible entry (and ``pos_ids`` equal everywhere — freed pages are
     invalidated), the paged engine's logits are bitwise identical to the
     contiguous path.
     """
+
+    donates = True  # the step's writes update the page buffers in place
 
     def __init__(self, model, slots: int, max_len: int,
                  page_size: int = 16, total_pages: Optional[int] = None):
@@ -466,6 +424,9 @@ class PagedKVCacheManager:
         self.slots = slots
         self.max_len = max_len
         self.page_size = page_size
+        # every cache of the stack holds full-length attention entries: the
+        # model steps the pool itself
+        self.kv_pool = model.decodes_in_pool
         # logical per-slot sequence extent: probe the contiguous abstract
         # build — ring caches clamp at min(max_len, window)
         ref = model.cache(slots, max_len, abstract=True)
@@ -523,6 +484,8 @@ class PagedKVCacheManager:
         self._slot_pages = np.zeros(slots, np.int32)
         self._pages_in_use = 0
         self.peak_pages = 0
+        self._tables_host: Optional[np.ndarray] = None  # what is on device
+        self._tables_dev: tuple = ()
 
         def _invalidate_pages(pool, page_ids):
             def inv(path, ba, pc):
@@ -541,7 +504,53 @@ class PagedKVCacheManager:
         self._gather = jax.jit(self.gather_logical)
         self._scatter = jax.jit(self.scatter_logical)
 
-    # -- pool <-> logical layout (traceable; fused into the engine step) ------
+    # -- the fused step's model call ------------------------------------------
+    def decode_step(self, params, pool, tables, tokens, pos, n_valid):
+        """The step's model call over the pool (traceable): in the pool
+        (``Model.decode_paged``) when ``kv_pool``, else gather ->
+        ``Model.decode`` -> :meth:`scatter_all` through the inverse map."""
+        if self.kv_pool:
+            (bt,) = tables
+            with jax.named_scope("decode"):
+                logits, out, stats = self.model.decode_paged(
+                    params, tokens, pool, bt, pos, n_valid=n_valid)
+            # the layers wrote their entries in place; the write-back hands
+            # the stepped pool on as it is
+            return logits, self.scatter_all(pool, out, None), stats
+        bt, inv = tables
+        with jax.named_scope("kv_gather"):
+            cache = self.gather_logical(pool, bt)
+        with jax.named_scope("decode"):
+            logits, cache = self.model.decode(params, tokens, cache, pos,
+                                              n_valid=n_valid)
+        with jax.named_scope("kv_scatter"):
+            return logits, self.scatter_all(pool, cache, inv), {}
+
+    def tables_stale(self) -> bool:
+        """True when the host block table differs from its device copy."""
+        return self._tables_host is None or not np.array_equal(
+            self._tables_host, self.block_table)
+
+    def tables(self) -> tuple:
+        """:meth:`decode_step`'s table arguments on the device: the block
+        table, and on the gather path its inverse page map too; re-uploaded
+        only when the host table changed (steady decode re-uses pages for
+        page_size ticks at a time, so most ticks skip the transfer)."""
+        if self.tables_stale():
+            self._tables_host = self.block_table.copy()
+            self._tables_dev = (jnp.asarray(self._tables_host, jnp.int32),)
+            if not self.kv_pool:
+                self._tables_dev += (jnp.asarray(self.inverse_map(),
+                                                 jnp.int32),)
+        return self._tables_dev
+
+    def warm_release(self):
+        """Compile the release path's invalidation ahead of traffic (on
+        the null page, which stays invalid)."""
+        self.pool = self._invalidate_pages(
+            self.pool, jnp.asarray([self.null_page]))
+
+    # -- pool <-> logical layout (traceable) ----------------------------------
     def gather_logical(self, pool, bt):
         """Gather block tables ``bt`` (n, pages) into a slot-contiguous
         logical cache (n, pages*page_size) — what ``Model.decode`` sees."""
@@ -627,6 +636,10 @@ class PagedKVCacheManager:
     @property
     def cache(self):
         return self.pool
+
+    @cache.setter
+    def cache(self, pool):
+        self.pool = pool
 
     @property
     def free_slots(self) -> List[int]:
@@ -743,9 +756,8 @@ class PagedKVCacheManager:
         return self._gather(self.pool, bt)
 
     def _fit_rows(self, rows):
-        """Pad logical rows out to the current block-table width (fill -1
-        for ``pos_ids``) — short preemption payloads and pre-growth
-        expandable stashes both land here."""
+        """Pad logical rows out to the block-table width (fill -1 for
+        ``pos_ids``) — short preemption payloads land here."""
         width = self.block_table.shape[1] * self.page_size
 
         def fit(path, ba, sa, row):
@@ -776,46 +788,3 @@ class PagedKVCacheManager:
         for s, n in zip(slot_ids, counts):
             self.pos[s] += int(n)
             self.extend(s, int(self.pos[s]))
-
-
-class ExpandablePagedKVCacheManager(PagedKVCacheManager):
-    """Paged manager whose per-slot capacity starts at ``initial_len`` and
-    doubles up to ``max_len``.  Growth only **widens the block tables**
-    with null-page (invalid) columns — live pages never relocate and the
-    physical pool (sized for ``max_len`` worth of pages up front) is
-    untouched, so grow-mid-decode is a host-side O(slots) operation."""
-
-    def __init__(self, model, slots: int, max_len: int,
-                 initial_len: int = 64, page_size: int = 16,
-                 total_pages: Optional[int] = None):
-        cfg = getattr(model, "cfg", None)
-        window = getattr(cfg, "sliding_window", 0) or 0
-        if window and window < max_len:
-            raise ValueError(
-                "expandable paged cache requires sliding_window >= max_len")
-        super().__init__(model, slots, max_len, page_size=page_size,
-                         total_pages=total_pages)
-        initial_len = min(max(initial_len, page_size), max_len)
-        init_pages = max(1, math.ceil(initial_len / page_size))
-        self.block_table = self.block_table[:, :init_pages].copy()
-        self.capacity = init_pages * page_size
-        self.grows = 0
-
-    def ensure(self, needed: int):
-        """Grow capacity (doubling) until >= needed tokens per slot; new
-        block-table columns point at the null page (invalid) until pages
-        are actually claimed by ``extend``."""
-        if needed <= self.capacity:
-            return
-        new_cap = self.capacity
-        while new_cap < needed:
-            new_cap = min(new_cap * 2, self.seq_len)
-            if new_cap == self.capacity:
-                raise ValueError(
-                    f"request needs {needed} tokens; max_len={self.max_len}")
-        width = new_cap // self.page_size
-        grown = np.full((self.slots, width), self.null_page, np.int32)
-        grown[:, :self.block_table.shape[1]] = self.block_table
-        self.block_table = grown
-        self.capacity = new_cap
-        self.grows += 1

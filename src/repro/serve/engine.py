@@ -14,15 +14,18 @@ Cache state lives in :class:`~repro.serve.cache.KVCacheManager` (or, with
 ``paged=True``, :class:`~repro.serve.cache.PagedKVCacheManager` — free-list
 pages behind per-slot block tables): per-slot positions, page accounting,
 slot recycling (freed rows/pages are invalidated via ``pos_ids = -1`` and
-reused without growing the arrays).  On the paged path, when every cache
-of the stack holds full-length attention entries (GQA K/V or MLA latents,
-dense prefix blocks included: ``Model.decodes_in_pool``), the entries never
-leave the page pool: the fused step hands the donated pool and the block
-tables to ``Model.decode_paged``, whose layers each write the tick's new
-entries into their pages and read their own pages through the tables.
-Ring caches of sliding-window models gather a slot-contiguous logical cache
-through the block tables, run ``Model.decode`` and scatter pages back.
-Either way it is one jit with outputs bitwise identical to the
+reused without growing the arrays).  The manager also owns how its storage
+meets the model, so the engine never branches on the layout: the one fused
+step calls the manager's ``decode_step`` over its state (``cache``) and its
+device ``tables()``, and donates the state where the manager ``donates``
+it.  On the paged path, when every cache of the stack holds full-length
+attention entries (GQA K/V or MLA latents, dense prefix blocks included:
+``Model.decodes_in_pool``, the manager's ``kv_pool``), the entries never
+leave the page pool: ``Model.decode_paged``'s layers each write the tick's
+new entries into their pages and read their own pages through the block
+tables.  Ring caches of sliding-window models gather a slot-contiguous
+logical cache through the block tables, run ``Model.decode`` and scatter
+pages back.  Either way it is one jit with outputs bitwise identical to the
 contiguous manager, and admission/extension run at page granularity off
 the actual free list, so churn that would fragment contiguous rows costs
 nothing.
@@ -47,9 +50,12 @@ Two scheduling paths, picked by model family:
   exact-length prefill per request and scatters the row; decode then joins
   the same fused step.
 
+Every non-speculative tick is committed one way: launched (positions
+advance at launch), then landed (synced, prompt progress and tokens
+committed).  Without overlap a tick lands in the step that launched it.
 Overlap: wherever it can (the in-pool path, without speculation) the
 engine runs one tick ahead of the host by default: each ``step()``
-launches tick n, then syncs and commits tick n-1, whose sampled tokens
+launches tick n, then lands tick n-1, whose sampled tokens
 feed tick n's decode rows on the device.  Composing, uploading and
 dispatching a tick then happen while the device still runs the one
 before, so they drop out of the gap between tokens.  A request's tokens
@@ -61,7 +67,8 @@ is launched, so a slot whose request ended on EOS runs one tick more,
 whose token is dropped.  Preemption and ``drain`` land the tick in flight
 first.  Ring caches, recurrent stacks, the gather path and speculation
 keep the synchronous step; ``overlap=False`` asks for it anywhere, and
-``overlap=True`` where the overlapped step cannot run raises.
+``overlap=True`` where the overlapped step cannot run raises.  A
+speculative tick verifies its drafts and commits them in its own step.
 
 Control-plane hooks (repro.control, DESIGN.md §3): EVERY ``step()`` emits a
 ``TickSample`` — including admit-only and fully-throttled iterations, so
@@ -103,17 +110,15 @@ from jax.profiler import TraceAnnotation as span
 from repro.control.telemetry import TickSample
 from repro.models.model import Model
 from repro.serve import scheduler as sched
-from repro.serve.cache import (ExpandableKVCacheManager,
-                               ExpandablePagedKVCacheManager, HostPagePool,
-                               KVCacheManager, PagedKVCacheManager)
+from repro.serve.cache import (HostPagePool, KVCacheManager,
+                               PagedKVCacheManager)
 from repro.serve.step import sample
 
 
 @dataclass
 class _Flight:
-    """A tick launched and not yet landed (overlap): its plan, the
-    request each slot served, and the device's sampled tokens and
-    counters."""
+    """A tick launched and not yet landed: its plan, the request each slot
+    served, and the device's sampled tokens and counters."""
     plan: sched.TickPlan
     reqs: dict
     out: jax.Array
@@ -161,8 +166,8 @@ class Engine:
                  temperature: float = 0.0,
                  admit_cap: Optional[int] = None,
                  top_k: int = 0, prefill_chunk: int = 16,
-                 page_size: int = 16, expandable: bool = False,
-                 paged: bool = False, total_pages: Optional[int] = None,
+                 page_size: int = 16, paged: bool = False,
+                 total_pages: Optional[int] = None,
                  speculate: int = 0, overlap: Optional[bool] = None,
                  seed: int = 0, warmup: bool = True,
                  pool: Optional[HostPagePool] = None):
@@ -202,15 +207,12 @@ class Engine:
                     "wrapping ring scatter would destroy live window "
                     "entries a rejected draft cannot restore")
         if self._paged:
-            mgr_cls = (ExpandablePagedKVCacheManager if expandable
-                       else PagedKVCacheManager)
-            self.mgr = mgr_cls(model, batch_slots, max_len,
-                               page_size=page_size, total_pages=total_pages)
+            self.mgr = PagedKVCacheManager(model, batch_slots, max_len,
+                                           page_size=page_size,
+                                           total_pages=total_pages)
         else:
-            mgr_cls = (ExpandableKVCacheManager if expandable
-                       else KVCacheManager)
-            self.mgr = mgr_cls(model, batch_slots, max_len,
-                               page_size=page_size)
+            self.mgr = KVCacheManager(model, batch_slots, max_len,
+                                      page_size=page_size)
         self.slot_req: List[Optional[Request]] = [None] * self.B
         self.queue: List[Request] = []
         self.finished: List[Request] = []
@@ -220,18 +222,11 @@ class Engine:
         self.preempts = 0
         self.spec_proposed = 0  # draft tokens offered to verification
         self.spec_accepted = 0  # draft tokens accepted (bitwise == greedy)
-        self._bt_host: Optional[np.ndarray] = None  # device bt cache key
-        self._bt_dev = None
         self.key = jax.random.PRNGKey(seed)
         # control plane: admission throttle + tick telemetry subscribers
         self.admit_cap = admit_cap
         self.on_tick: List[Callable[[TickSample], None]] = []
         self.ticks = 0
-
-        def decode(params, tokens, cache, pos, n_valid):
-            with jax.named_scope("decode"):
-                return model.decode(params, tokens, cache, pos,
-                                     n_valid=n_valid)
 
         def pick(logits, n_valid, key):
             """Each slot's next token, from the logit after its last valid
@@ -247,8 +242,8 @@ class Engine:
             with jax.named_scope("sample"):
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-        self._kv_pool = self._paged and model.decodes_in_pool
-        can_overlap = self._kv_pool and not self._spec_k
+        mgr = self.mgr
+        can_overlap = mgr.kv_pool and not self._spec_k
         if overlap and not can_overlap:
             raise ValueError(
                 "overlap requires the in-pool paged step (paged=True on a "
@@ -262,59 +257,22 @@ class Engine:
         # given at least one token
         self.routed_local = 0
         self.experts_touched = 0
-        if self._kv_pool:
-            mgr = self.mgr
 
-            def decode_paged(params, tokens, pool, bt, pos, n_valid):
-                with jax.named_scope("decode"):
-                    logits, out, stats = model.decode_paged(
-                        params, tokens, pool, bt, pos, n_valid=n_valid)
-                # the layers wrote their entries in place; the manager's
-                # write-back hands the stepped pool on as it is
-                return logits, mgr.scatter_all(pool, out, None), stats
+        # one step for every manager: (params, state, *tables, tokens, pos,
+        # n_valid, key), the state donated where the manager donates it
+        def fused(params, state, *args):
+            *tables, tokens, pos, n_valid, key = args
+            logits, state, stats = mgr.decode_step(params, state, tables,
+                                                   tokens, pos, n_valid)
+            return pick(logits, n_valid, key), state, stats
 
-            def fused(params, pool, bt, tokens, pos, n_valid, key):
-                logits, pool, stats = decode_paged(params, tokens, pool, bt,
-                                                   pos, n_valid)
-                return pick(logits, n_valid, key), pool, stats
+        def fused_spec(params, state, *args):
+            *tables, tokens, pos, n_valid, key = args
+            logits, state, stats = mgr.decode_step(params, state, tables,
+                                                   tokens, pos, n_valid)
+            return verify_rows(logits), state, stats
 
-            def fused_spec(params, pool, bt, tokens, pos, n_valid, key):
-                logits, pool, stats = decode_paged(params, tokens, pool, bt,
-                                                   pos, n_valid)
-                return verify_rows(logits), pool, stats
-        elif self._paged:
-            mgr = self.mgr
-
-            def gather(pool, bt):
-                with jax.named_scope("kv_gather"):
-                    return mgr.gather_logical(pool, bt)
-
-            def scatter(pool, cache, inv):
-                with jax.named_scope("kv_scatter"):
-                    return mgr.scatter_all(pool, cache, inv)
-
-            def fused(params, pool, bt, inv, tokens, pos, n_valid, key):
-                logits, cache = decode(params, tokens, gather(pool, bt), pos,
-                                       n_valid)
-                return (pick(logits, n_valid, key),
-                        scatter(pool, cache, inv), {})
-
-            def fused_spec(params, pool, bt, inv, tokens, pos, n_valid, key):
-                logits, cache = decode(params, tokens, gather(pool, bt), pos,
-                                       n_valid)
-                return verify_rows(logits), scatter(pool, cache, inv), {}
-        else:
-            def fused(params, cache, tokens, pos, n_valid, key):
-                logits, cache = decode(params, tokens, cache, pos, n_valid)
-                return pick(logits, n_valid, key), cache, {}
-
-            def fused_spec(params, cache, tokens, pos, n_valid, key):
-                logits, cache = decode(params, tokens, cache, pos, n_valid)
-                return verify_rows(logits), cache, {}
-
-        # the paged step donates the pool: its writes then update the page
-        # buffers in place instead of copying the whole pool
-        donate = (1,) if self._paged else ()
+        donate = (1,) if mgr.donates else ()
         self._fused = jax.jit(fused, donate_argnums=donate)
         self._fused_spec = jax.jit(fused_spec, donate_argnums=donate)
 
@@ -329,42 +287,30 @@ class Engine:
         if warmup:
             self._warmup()
 
-    def _run_fused(self, fn, plan: sched.TickPlan) -> Tuple[np.ndarray, dict]:
-        """One fused device step over the plan (on the paged path: in the
-        pool, or gather -> decode -> scatter) under the upload, dispatch
-        and sync spans; returns the host copy of the sampled output and the
-        tick's expert counters (empty where the step has none)."""
-        return self._sync(*self._launch(fn, plan))
-
     def _launch(self, fn, plan: sched.TickPlan,
                 prev: Optional[_Flight] = None):
         """Upload the tick's arrays and dispatch the fused step; returns its
         device output and counters.  ``prev`` is the tick in flight: its
         sampled tokens fill the plan's -1 decode inputs on the device."""
         with span("serve.engine.upload",
-                  bt_sent=int(self._paged and self._bt_stale())):
+                  bt_sent=int(self.mgr.tables_stale())):
             self.key, key = jax.random.split(self.key)
             toks = jnp.asarray(plan.tokens)
             if prev is not None and (plan.tokens < 0).any():
                 toks = self._feed(toks, prev.out)
             pos = jnp.asarray(plan.pos)
             nv = jnp.asarray(plan.n_valid)
-            if self._paged:
-                tables = self._bt_device()
+            tables = self.mgr.tables()
         prefill = [w for w in plan.work if w.kind == "prefill"]
         ahead = int(prev is not None)
         self.ticks_ahead += ahead
         with span("serve.engine.dispatch", width=plan.width,
                   prefill=len(prefill), decode=len(plan.work) - len(prefill),
                   prompt_tokens=sum(len(w.tokens) for w in prefill),
-                  kv_pool=int(self._kv_pool), ahead=ahead):
-            if self._paged:
-                out, self.mgr.pool, stats = fn(self.params, self.mgr.pool,
-                                               *tables, toks, pos, nv, key)
-                self.kv_pool_ticks += self._kv_pool
-            else:
-                out, self.mgr.cache, stats = fn(self.params, self.mgr.cache,
-                                                toks, pos, nv, key)
+                  kv_pool=int(self.mgr.kv_pool), ahead=ahead):
+            out, self.mgr.cache, stats = fn(self.params, self.mgr.cache,
+                                            *tables, toks, pos, nv, key)
+            self.kv_pool_ticks += self.mgr.kv_pool
         return out, stats
 
     def _sync(self, out, stats) -> Tuple[np.ndarray, dict]:
@@ -376,29 +322,11 @@ class Engine:
         self.experts_touched += stats.get("experts_touched", 0)
         return out, stats
 
-    def _bt_stale(self) -> bool:
-        """True when the host block table differs from its device copy."""
-        return self._bt_host is None or not np.array_equal(
-            self._bt_host, self.mgr.block_table)
-
-    def _bt_device(self) -> tuple:
-        """The fused step's page-table arguments on the device: the block
-        table, and on the gather/scatter path its inverse page map too;
-        re-uploaded only when the host table actually changed (steady
-        decode re-uses pages for page_size ticks at a time, so most ticks
-        skip the transfer)."""
-        if self._bt_stale():
-            self._bt_host = self.mgr.block_table.copy()
-            self._bt_dev = (jnp.asarray(self._bt_host, jnp.int32),)
-            if not self._kv_pool:
-                self._bt_dev += (jnp.asarray(self.mgr.inverse_map(),
-                                             jnp.int32),)
-        return self._bt_dev
-
     def _warmup(self):
         """Pre-compile the fused step's width buckets and the invalidation
-        paths so no compile lands mid-traffic (n_valid = 0 rows make the
-        warmup calls no-ops on cache contents)."""
+        paths so no compile lands mid-traffic.  The calls' n_valid = 0 rows
+        leave every ``pos_ids`` invalid; a state the step does not donate
+        is kept as it was (the calls would step recurrent state)."""
         widths = {1, self.prefill_chunk} if self._ragged else {1}
         zero = jnp.zeros((self.B,), jnp.int32)
         calls = [(self._fused, S) for S in sorted(widths)]
@@ -406,21 +334,13 @@ class Engine:
             calls.append((self._fused_spec, self._spec_k + 1))
         for fn, S in calls:
             toks = jnp.zeros((self.B, S), jnp.int32)
-            if self._paged:
-                # the pool is donated into the jit — rebind the returned
-                # buffer or the manager would hold a deleted array
-                out, self.mgr.pool, _ = fn(self.params, self.mgr.pool,
-                                           *self._bt_device(), toks, zero,
-                                           zero, self.key)
-                if self._overlap:
-                    self._feed(toks, out)
-            else:
-                fn(self.params, self.mgr.cache, toks, zero, zero, self.key)
-        if self._paged:
-            self.mgr.pool = self.mgr._invalidate_pages(
-                self.mgr.pool, jnp.asarray([self.mgr.null_page]))
-        else:
-            self.mgr._invalidate(self.mgr.cache, jnp.asarray([0]))
+            out, state, _ = fn(self.params, self.mgr.cache,
+                               *self.mgr.tables(), toks, zero, zero, self.key)
+            if self.mgr.donates:  # the call deleted it: keep what it returned
+                self.mgr.cache = state
+            if self._overlap:
+                self._feed(toks, out)
+        self.mgr.warm_release()
 
     # -- public API -----------------------------------------------------------
     @property
@@ -455,9 +375,6 @@ class Engine:
                 # the host page pool bit for bit — no recompute, no drift
                 slot = self.mgr.allocate(len(req.prompt))
                 rows, pos = self.pool.take(req.rid, owner=self.mgr)
-                if isinstance(self.mgr, (ExpandableKVCacheManager,
-                                         ExpandablePagedKVCacheManager)):
-                    self.mgr.ensure(pos + 1)
                 self.mgr.restore(slot, rows, pos)
                 self.slot_req[slot] = req
                 admitted += 1
@@ -528,13 +445,8 @@ class Engine:
     def _prefill_into(self, slot: int, req: Request):
         """Stateful-family path: exact-length prefill, scatter one row."""
         toks = jnp.asarray(np.asarray(req.prompt, np.int32)[None])
-        if isinstance(self.mgr, ExpandableKVCacheManager):
-            self.mgr.ensure(len(req.prompt) + 1)
-            cap = self.mgr.capacity
-        else:
-            cap = self.max_len
         logits, rows = self.model.prefill(self.params, {"tokens": toks},
-                                          max_len=cap)
+                                          max_len=self.max_len)
         self.mgr.write_rows([slot], rows)
         self.mgr.advance([slot], [len(req.prompt)])
         req.fed = len(req.prompt)
@@ -617,8 +529,6 @@ class Engine:
         if plan is None:
             return None, False
         if self._paged:
-            if isinstance(self.mgr, ExpandablePagedKVCacheManager):
-                self.mgr.ensure(int(plan.pos.max() + plan.width))
             while not self._reserve_pages(plan):
                 # out of pages mid-decode: thermal-preempt the newest
                 # low-priority request (pages return to the free list,
@@ -632,33 +542,21 @@ class Engine:
                 plan, spec = self._compose()
                 if plan is None:
                     return None, False
-                if isinstance(self.mgr, ExpandablePagedKVCacheManager):
-                    self.mgr.ensure(int(plan.pos.max() + plan.width))
-        elif isinstance(self.mgr, ExpandableKVCacheManager):
-            self.mgr.ensure(int(plan.pos.max() + plan.width))
         return plan, spec
 
     def _tick(self) -> int:
+        """Launch the planned tick, then land the one in flight.  Positions
+        advance at launch, so the next plan writes after this tick's
+        entries; prompt progress and tokens are committed when the tick
+        lands.  The tick lands in its own step unless the engine overlaps,
+        and also when every held request ends in it: no tick could follow
+        it.  A speculative tick verifies and commits in its own step."""
         with span("serve.engine.compose"):
             plan, spec = self._plan()
-        if self._overlap:
-            return self._tick_ahead(plan)
-        if plan is None:
-            return 0
         if spec:
-            rows, stats = self._run_fused(self._fused_spec, plan)  # (B, k+1)
+            rows, stats = self._sync(*self._launch(self._fused_spec, plan))
             with span("serve.engine.commit", **stats):
-                return self._commit_spec(plan, rows)
-        nxt, stats = self._run_fused(self._fused, plan)
-        with span("serve.engine.commit", **stats):
-            return self._commit(plan, nxt)
-
-    def _tick_ahead(self, plan: Optional[sched.TickPlan]) -> int:
-        """``overlap``: launch the planned tick, then land the one in
-        flight.  Positions advance at launch, so the next plan writes
-        after this tick's entries; prompt progress and tokens are
-        committed when the tick lands.  A launched tick that every held
-        request ends in lands at once: no tick could follow it."""
+                return self._commit_spec(plan, rows)  # rows: (B, k+1)
         prev = self._take_inflight()
         if plan is not None:
             out, stats = self._launch(self._fused, plan, prev)
@@ -669,9 +567,9 @@ class Engine:
                 out, stats)
         gen = self._land(prev)
         fl = self._inflight
-        if fl is not None and all(self._ends_in(fl, s, r)
-                                  for s, r in enumerate(self.slot_req)
-                                  if r is not None):
+        if fl is not None and (not self._overlap or all(
+                self._ends_in(fl, s, r)
+                for s, r in enumerate(self.slot_req) if r is not None)):
             gen += self._land(self._take_inflight())
         return gen
 
@@ -704,24 +602,6 @@ class Engine:
                     continue
                 self._append(req, w.slot, int(nxt[w.slot]),
                              end=int(fl.plan.pos[w.slot]) + len(w.tokens))
-                gen += 1
-        return gen
-
-    def _commit(self, plan: sched.TickPlan, nxt: np.ndarray) -> int:
-        """Advance every slot the tick fed and append its sampled token
-        (a prefill slot only once its last prompt chunk is in)."""
-        gen = 0
-        self.mgr.advance([w.slot for w in plan.work],
-                         [len(w.tokens) for w in plan.work])
-        for w in plan.work:
-            req = self.slot_req[w.slot]
-            if w.kind == "prefill":
-                req.fed += len(w.tokens)
-                if w.completes:  # logit after the last prompt token
-                    self._append(req, w.slot, int(nxt[w.slot]))
-                    gen += 1
-            else:
-                self._append(req, w.slot, int(nxt[w.slot]))
                 gen += 1
         return gen
 

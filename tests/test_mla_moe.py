@@ -177,7 +177,7 @@ class TestLatentPool:
             "dense0": {"latent": (pages, 8, width), "pos_ids": (pages, 8)},
             "stack": {"latent": (cfg.num_layers - 1, pages, 8, width),
                       "pos_ids": (cfg.num_layers - 1, pages, 8)}}
-        assert model.decodes_in_pool and eng._kv_pool
+        assert model.decodes_in_pool and eng.mgr.kv_pool
 
     def test_write_near_max_len_keeps_live_entries(self, mla):
         """A slot decoding at position 54 of 64 keeps every cached latent,

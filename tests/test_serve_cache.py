@@ -1,4 +1,4 @@
-"""KVCacheManager / ExpandableKVCacheManager (repro.serve.cache)."""
+"""KVCacheManager (repro.serve.cache)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,8 +6,7 @@ import pytest
 
 from repro.configs import registry
 from repro.models.model import Model
-from repro.serve.cache import (NO_AXIS, ExpandableKVCacheManager,
-                               KVCacheManager)
+from repro.serve.cache import NO_AXIS, KVCacheManager
 
 
 @pytest.fixture(scope="module")
@@ -16,10 +15,6 @@ def dense():
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     return cfg, model, params
-
-
-def _leaf_shapes(cache):
-    return [x.shape for x in jax.tree_util.tree_leaves(cache)]
 
 
 class TestKVCacheManager:
@@ -119,45 +114,3 @@ class TestKVCacheManager:
         ref = np.asarray(rows["stack"]["k"], np.float32)[:, 0]
         np.testing.assert_allclose(got[:, 2], ref, rtol=1e-6)
         assert (got[:, 0] == 0).all()  # other slots untouched
-
-
-class TestExpandableKVCacheManager:
-    def test_grows_by_doubling_to_max_len(self, dense):
-        _, model, _ = dense
-        mgr = ExpandableKVCacheManager(model, slots=2, max_len=64,
-                                       initial_len=8)
-        assert mgr.capacity == 8
-        shapes0 = _leaf_shapes(mgr.cache)
-        mgr.ensure(8)
-        assert mgr.capacity == 8 and _leaf_shapes(mgr.cache) == shapes0
-        mgr.ensure(9)
-        assert mgr.capacity == 16 and mgr.grows == 1
-        mgr.ensure(50)  # doubles twice in one call
-        assert mgr.capacity == 64 and mgr.grows == 2
-        with pytest.raises(ValueError):
-            mgr.ensure(65)
-
-    def test_growth_pads_pos_ids_invalid(self, dense):
-        _, model, _ = dense
-        mgr = ExpandableKVCacheManager(model, slots=2, max_len=32,
-                                       initial_len=8)
-        mgr.cache["stack"]["pos_ids"] = (
-            mgr.cache["stack"]["pos_ids"].at[..., :2].set(0))
-        mgr.ensure(16)
-        ids = np.asarray(mgr.cache["stack"]["pos_ids"])
-        assert ids.shape[-1] == 16
-        assert (ids[..., :2] == 0).all()   # old contents preserved
-        assert (ids[..., 8:] == -1).all()  # new space invalid, not pos 0
-
-    def test_engine_results_match_fixed_cache(self, dense):
-        from repro.serve.engine import Engine, Request
-        cfg, model, params = dense
-        prompt = np.arange(5) % cfg.vocab_size
-
-        def gen(expandable):
-            eng = Engine(model, params, batch_slots=2, max_len=64,
-                         eos_id=-1, expandable=expandable, warmup=False)
-            eng.submit(Request(0, prompt, max_new=6))
-            return eng.run()[0].out
-
-        assert gen(False) == gen(True)

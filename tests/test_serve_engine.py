@@ -190,26 +190,6 @@ class TestPreemption:
         eng.preempt_to(0)
         assert eng.run()[0].out == ref
 
-    def test_resume_across_expandable_growth(self, dense):
-        cfg, model, params = dense
-        prompt = np.arange(5) % cfg.vocab_size
-        solo = _eng(model, params, expandable=True)
-        solo.submit(Request(0, prompt, max_new=12))
-        ref = solo.run()[0].out
-
-        eng = _eng(model, params, expandable=True)
-        eng.submit(Request(0, prompt, max_new=12))
-        for _ in range(2):
-            eng.step()
-        eng.preempt_to(0)
-        # the cache regrows while the rows sit in the host pool: restore
-        # must pad the stashed rows out to the new leaf shapes
-        eng.submit(Request(1, (np.arange(9) * 3 + 2) % cfg.vocab_size,
-                           max_new=12))
-        done = {r.rid: r for r in eng.run()}
-        assert done[0].out == ref
-        assert done[0].preempts == 1
-
     def test_preempt_to_is_a_noop_when_under_cap(self, dense):
         cfg, model, params = dense
         eng = _eng(model, params)

@@ -21,7 +21,7 @@ import pytest
 from repro import scenarios as sc
 from repro.configs import registry
 from repro.models.model import Model
-from repro.serve.cache import (ExpandablePagedKVCacheManager, PageAllocator,
+from repro.serve.cache import (KVCacheManager, PageAllocator,
                                PagedKVCacheManager)
 from repro.serve.engine import Engine, Request
 
@@ -322,32 +322,6 @@ class TestChurnAdmission:
                 == {r.rid: tuple(r.out) for r in eng_p.finished})
 
 
-class TestExpandablePagedGrowth:
-    def test_growth_widens_tables_without_relocating_pages(self, dense):
-        _, model, _ = dense
-        mgr = ExpandablePagedKVCacheManager(model, slots=2, max_len=64,
-                                            initial_len=16, page_size=16)
-        assert mgr.capacity == 16 and mgr.block_table.shape[1] == 1
-        s = mgr.allocate(5)
-        live = int(mgr.block_table[s, 0])
-        mgr.ensure(40)
-        assert mgr.capacity == 64 and mgr.grows >= 1
-        assert mgr.block_table[s, 0] == live  # live page never relocates
-        assert (mgr.block_table[:, 1:] == mgr.null_page).all()  # new: invalid
-        assert mgr.pages_in_use == mgr.recount_pages() == 1
-        mgr.advance([s], [40])  # claim across the grown width
-        assert mgr.block_table[s, 0] == live
-        assert mgr.slot_pages(s) == 3
-        assert mgr.peak_pages == 3  # no undercount from the growth
-
-    def test_engine_results_match_contiguous(self, dense):
-        cfg, model, params = dense
-        _, ref = _outs(cfg, model, params, n_req=3, max_new=20)
-        _, exp = _outs(cfg, model, params, n_req=3, max_new=20,
-                       paged=True, expandable=True)
-        assert ref == exp
-
-
 class TestPagedPreemption:
     def test_page_exact_eviction_and_bitwise_resume(self, dense):
         cfg, model, params = dense
@@ -390,13 +364,15 @@ WAVES = [(0, 0, 12, 9), (0, 1, 5, 14), (2, 2, 11, 6), (5, 3, 3, 10),
 
 def _serve_waves(model, params, cfg, on_fused=None, **kw):
     """Serve WAVES through an engine; ``on_fused(eng, run, fn, plan)``
-    wraps every fused tick.  Returns the engine and the tokens by rid."""
+    wraps every fused tick's launch.  Returns the engine and the tokens by
+    rid."""
     opts = dict(batch_slots=3, max_len=64, page_size=8, prefill_chunk=8,
                 eos_id=-1, warmup=False)
     eng = Engine(model, params, **{**opts, **kw})
     if on_fused is not None:
-        run = eng._run_fused
-        eng._run_fused = lambda fn, plan: on_fused(eng, run, fn, plan)
+        launch = eng._launch
+        eng._launch = lambda fn, plan, prev=None: on_fused(
+            eng, lambda f, p: launch(f, p, prev), fn, plan)
     due = list(WAVES)
     tick = 0
     while due or eng.step():
@@ -535,6 +511,126 @@ class TestInPoolStep:
         assert eng.mgr.pages_in_use == eng.mgr.recount_pages() == 0
 
 
+KINDS = ["contiguous", "gather", "in_pool"]
+
+
+def _manager(kind, dense, swa):
+    """A two-slot manager of ``kind``: contiguous and in-pool serve the
+    dense stack, the gather path the sliding-window one."""
+    cfg, model, params = swa if kind == "gather" else dense
+    cls = KVCacheManager if kind == "contiguous" else PagedKVCacheManager
+    return cfg, model, params, cls(model, slots=2, max_len=32, page_size=8)
+
+
+def _same(a, b):
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+class TestManagerStep:
+    """Each manager owns how its storage meets the model: ``decode_step``
+    is ``Model.decode`` on the contiguous cache, ``Model.decode_paged`` in
+    the pool, and ``Model.decode`` between the block-table gather and the
+    write-back on the gather path; the engine's one fused step calls it."""
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_manager_step_is_the_model_call(self, dense, swa, kind):
+        import jax.numpy as jnp
+        cfg, model, params, mgr = _manager(kind, dense, swa)
+        assert (mgr.kv_pool, mgr.donates) == (kind == "in_pool",
+                                              kind != "contiguous")
+        for n in (5, 11):
+            mgr.allocate(n)
+        state = ref = mgr.cache
+        # a prompt chunk (one slot partial), then a decode tick
+        ticks = [(np.arange(16).reshape(2, 8) * 3 % cfg.vocab_size, [5, 8]),
+                 (np.asarray([[7], [9]]), [1, 1])]
+        for tokens, n_valid in ticks:
+            toks = jnp.asarray(tokens, jnp.int32)
+            # a copy: advance moves mgr.pos, maybe before the upload reads it
+            pos = jnp.asarray(mgr.pos.copy())
+            nv = jnp.asarray(n_valid, jnp.int32)
+            mgr.advance([0, 1], n_valid)  # paged: claims the pages written
+            tables = mgr.tables()
+            logits, state, stats = mgr.decode_step(params, state, tables,
+                                                   toks, pos, nv)
+            if kind == "contiguous":
+                assert tables == () and not mgr.tables_stale()
+                want, ref = model.decode(params, toks, ref, pos, n_valid=nv)
+                _same((logits, state, stats), (want, ref, {}))
+            elif kind == "in_pool":
+                want, ref, got = model.decode_paged(params, toks, ref,
+                                                    tables[0], pos,
+                                                    n_valid=nv)
+                _same((logits, state, stats), (want, ref, got))
+            else:
+                bt, _ = tables
+                want, logical = model.decode(
+                    params, toks, mgr.gather_logical(ref, bt), pos,
+                    n_valid=nv)
+                # the pool holds what the model call wrote, page for page
+                _same((logits, mgr.gather_logical(state, bt), stats),
+                      (want, logical, {}))
+                ids = np.asarray(state["stack"]["pos_ids"])
+                assert (ids[:, mgr.null_page] == -1).all()
+                ref = state
+
+    @pytest.mark.parametrize("kind", ["gather", "in_pool"])
+    def test_tables_upload_only_when_the_block_table_changes(self, dense,
+                                                             swa, kind):
+        _, _, _, mgr = _manager(kind, dense, swa)
+        a = mgr.allocate(5)
+        assert mgr.tables_stale()
+        first = mgr.tables()
+        assert not mgr.tables_stale() and mgr.tables() is first
+        mgr.advance([a], [6])  # inside its first page: the table stands
+        assert not mgr.tables_stale() and mgr.tables() is first
+        mgr.advance([a], [6])  # into a second page
+        assert mgr.tables_stale()
+        now = mgr.tables()
+        assert now is not first and mgr.tables() is now
+        np.testing.assert_array_equal(np.asarray(now[0]), mgr.block_table)
+        if kind == "gather":  # the write-back's inverse page map rides along
+            assert len(now) == 2
+            np.testing.assert_array_equal(np.asarray(now[1]),
+                                          mgr.inverse_map())
+        else:
+            assert len(now) == 1
+        mgr.free(a)
+        assert mgr.tables_stale()
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_synchronous_tick_lands_in_its_own_step(self, dense, swa, kind):
+        """``overlap=False``: every step lands the tick it launched, so no
+        tick is in flight after any step and none is launched ahead; the
+        tokens are the overlapped in-pool engine's (the contiguous
+        engine's for the sliding-window stack, which cannot overlap)."""
+        cfg, model, params, _ = _manager(kind, dense, swa)
+
+        def serve(**kw):
+            eng = Engine(model, params, batch_slots=3, max_len=64,
+                         page_size=8, prefill_chunk=8, eos_id=-1,
+                         warmup=False, **kw)
+            inflight = []
+            eng.on_tick.append(lambda _: inflight.append(eng._inflight))
+            for _, rid, n, m in WAVES:
+                eng.submit(Request(rid, _prompt(cfg, rid, n), max_new=m))
+            eng.run()
+            return eng, inflight, {r.rid: tuple(r.out) for r in eng.finished}
+
+        eng, inflight, got = serve(paged=kind != "contiguous", overlap=False)
+        assert len(inflight) == eng.ticks > 0
+        assert all(fl is None for fl in inflight) and eng.ticks_ahead == 0
+        if kind == "gather":
+            _, _, ref = serve(paged=False)
+        else:
+            ahead, _, ref = serve(paged=True)
+            assert ahead.ticks_ahead > 0
+        assert got == ref
+
+
 @pytest.fixture(scope="module")
 def qwen3():
     cfg = registry.get("qwen3-1.7b").reduced()
@@ -569,7 +665,7 @@ class TestGqaStepPinned:
         slots = jax.ShapeDtypeStruct((3,), jnp.int32)
         text = eng._fused.lower(
             model.abstract_params(), shape(eng.mgr.pool),
-            *shape(eng._bt_device()),
+            *shape(eng.mgr.tables()),
             jax.ShapeDtypeStruct((3, width), jnp.int32), slots, slots,
             shape(eng.key)).as_text(debug_info=False)
         assert hashlib.sha256(text.encode()).hexdigest() == \

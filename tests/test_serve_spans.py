@@ -172,7 +172,7 @@ def test_gather_path_op_metadata_names_its_layers(swa, spec):
     """A sliding-window stack keeps gather -> decode -> scatter, and the
     step's op metadata names those layers."""
     eng = _engine(swa, speculate=2 if spec else 0)
-    assert not eng._kv_pool
+    assert not eng.mgr.kv_pool
     names = _fused_op_names(eng, spec)
     for scope in ("kv_gather", "decode", "sample", "kv_scatter"):
         assert any(f"/{scope}/" in n for n in names), scope
@@ -193,7 +193,7 @@ def test_latent_expert_step_names_its_layers(mla):
     like GQA K/V, and each expert layer's parts under ``moe_route``,
     ``moe_experts`` and ``moe_shared``."""
     eng = _engine(mla)
-    assert eng._kv_pool
+    assert eng.mgr.kv_pool
     names = _fused_op_names(eng, False)
     for scope in ("decode", "kv_write", "kv_read", "moe_route",
                   "moe_experts", "moe_shared", "sample"):
@@ -268,10 +268,7 @@ def _fused_op_names(eng, spec):
     args = (jax.numpy.zeros((B, S_), jax.numpy.int32),
             jax.numpy.zeros((B,), jax.numpy.int32),
             jax.numpy.zeros((B,), jax.numpy.int32), eng.key)
-    if eng._paged:
-        lowered = fn.lower(eng.params, eng.mgr.pool, *eng._bt_device(), *args)
-    else:
-        lowered = fn.lower(eng.params, eng.mgr.cache, *args)
+    lowered = fn.lower(eng.params, eng.mgr.cache, *eng.mgr.tables(), *args)
     return re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
 
 

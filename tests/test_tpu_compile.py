@@ -93,7 +93,7 @@ def test_paged_fused_decode_step_qwen3(one_chip):
     slots = _spec((8,), jnp.int32, one_chip)
     compiled = eng._fused.lower(
         on_chip(model.abstract_params()), on_chip(eng.mgr.pool),
-        *on_chip(eng._bt_device()), _spec((8, 1), jnp.int32, one_chip),
+        *on_chip(eng.mgr.tables()), _spec((8, 1), jnp.int32, one_chip),
         slots, slots, on_chip(eng.key)).compile()
     m = compiled.memory_analysis()
     # the pool is donated: the writes land in place (the device layout
@@ -124,7 +124,7 @@ def test_paged_fused_decode_step_deepseek_v2(one_chip):
     slots = _spec((16,), jnp.int32, one_chip)
     compiled = eng._fused.lower(
         on_chip(model.abstract_params()), on_chip(eng.mgr.pool),
-        *on_chip(eng._bt_device()), _spec((16, 1), jnp.int32, one_chip),
+        *on_chip(eng.mgr.tables()), _spec((16, 1), jnp.int32, one_chip),
         slots, slots, on_chip(eng.key)).compile()
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= sum(
